@@ -31,6 +31,8 @@ import traceback
 
 
 def main() -> None:
+    from repro.launch import compile_cache
+    compile_cache.enable()
     from benchmarks import (bench_controller, bench_controlplane,
                             bench_distill, bench_dse_sweep, bench_early_exit,
                             bench_fleet, bench_kernels, bench_latency,

@@ -6,9 +6,9 @@ view independently.  We keep that decoupling: weights are stored as
 ``[4, in, hidden]`` stacks (gate axis first) and the masked views are applied
 per-gate before the gate matmuls.
 
-On the FPGA each gate had its own MVM unit (Fig. 2).  On TPU the four gate
-matmuls are a single ``[B,4,I] × [4,I,H]`` batched contraction — one MXU pass,
-the fusion analogue of the paper's 1:1 DSP unrolling.  A Pallas-fused version
+On the FPGA each gate had its own MVM unit (Fig. 2); here each gate is its
+own ``[B,I] × [I,H]`` matmul too, in the form the kernels use, so the jnp
+path and the kernels accumulate in the same order.  A Pallas-fused version
 of the full step (masks + matmuls + nonlinearities + cell update) lives in
 ``repro.kernels.mcd_lstm``; this module is the composable/jnp path and the
 numerical ground truth for it.
@@ -91,6 +91,18 @@ def _pin_operands(*ops):
     return ops
 
 
+def _gate_dot(v, w, g: int):
+    """Gate ``g``'s fp32-accumulated matmul, ``[B,G,D] × [G,D,H] → [B,H]``.
+
+    One 2-D dot per gate — the kernels' exact formulation.  A single
+    batched contraction over the gate axis would be one MXU pass on TPU,
+    but it accumulates in another order than the kernels' per-gate dots on
+    the CPU backend (breaking bit-identity), and the CPU runtime refuses
+    its bf16×bf16→fp32 form outright.
+    """
+    return jnp.dot(v[:, g], w[g], preferred_element_type=jnp.float32)
+
+
 def lstm_step(params: LSTMParams, h: jax.Array, c: jax.Array, x: jax.Array,
               zx: jax.Array | None, zh: jax.Array | None, p: float,
               compute_dtype=None, det: jax.Array | None = None):
@@ -118,15 +130,12 @@ def lstm_step(params: LSTMParams, h: jax.Array, c: jax.Array, x: jax.Array,
         xg = jnp.where(det[:, None, None], xr, xg)
         hg = jnp.where(det[:, None, None], hr, hg)
     xg, hg, wxc, whc = _pin_operands(xg, hg, wx.astype(cd), wh.astype(cd))
-    gates = (jnp.einsum("bgi,gih->bgh", xg, wxc,
-                        preferred_element_type=jnp.float32)
-             + jnp.einsum("bgh,ghk->bgk", hg, whc,
-                          preferred_element_type=jnp.float32)
-             + b.astype(jnp.float32))
-    i = jax.nn.sigmoid(gates[:, 0])
-    f = jax.nn.sigmoid(gates[:, 1])
-    g = jnp.tanh(gates[:, 2])
-    o = jax.nn.sigmoid(gates[:, 3])
+    gates = [_gate_dot(xg, wxc, g) + _gate_dot(hg, whc, g)
+             + b[g].astype(jnp.float32) for g in range(4)]
+    i = jax.nn.sigmoid(gates[0])
+    f = jax.nn.sigmoid(gates[1])
+    g = jnp.tanh(gates[2])
+    o = jax.nn.sigmoid(gates[3])
     c_new = f * c.astype(jnp.float32) + i * g           # fp32 cell state
     h_new = (o * jnp.tanh(c_new)).astype(h.dtype)
     return h_new, c_new.astype(c.dtype)
@@ -178,15 +187,13 @@ def gru_step(params: GRUParams, h: jax.Array, x: jax.Array,
         xg = jnp.where(det[:, None, None], xr, xg)
         hg = jnp.where(det[:, None, None], hr, hg)
     xg, hg, wxc, whc = _pin_operands(xg, hg, wx.astype(cd), wh.astype(cd))
-    gx = jnp.einsum("bgi,gih->bgh", xg, wxc,
-                    preferred_element_type=jnp.float32)
-    gh = jnp.einsum("bgh,ghk->bgk", hg, whc,
-                    preferred_element_type=jnp.float32)
+    gx = [_gate_dot(xg, wxc, g) for g in range(3)]
+    gh = [_gate_dot(hg, whc, g) for g in range(3)]
     bf = b.astype(jnp.float32)
-    r = jax.nn.sigmoid(gx[:, 0] + gh[:, 0] + bf[0])
-    zt = jax.nn.sigmoid(gx[:, 1] + gh[:, 1] + bf[1])
+    r = jax.nn.sigmoid(gx[0] + gh[0] + bf[0])
+    zt = jax.nn.sigmoid(gx[1] + gh[1] + bf[1])
     # The candidate's bias stays outside the reset product (r gates only the
     # recurrent matmul) — the kernels replicate this placement exactly.
-    n = jnp.tanh(gx[:, 2] + r * gh[:, 2] + bf[2])
+    n = jnp.tanh(gx[2] + r * gh[2] + bf[2])
     h_new = (1.0 - zt) * n + zt * h.astype(jnp.float32)
     return h_new.astype(h.dtype)

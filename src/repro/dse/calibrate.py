@@ -65,6 +65,10 @@ def tick_raw_seconds(arch: RNNArch, *, rows: float, capacity: int,
                      shards: int = 1) -> float:
     """Uncalibrated roofline time for one engine tick.
 
+    The roofline is the modeled chip's (``repro.dse.tpu_model``) whatever
+    device served the ticks: :class:`RooflineFit`'s ``scale`` is what maps
+    it onto the device that was observed.
+
     A tick launches ``rows`` batch rows (sessions × S chains, padding
     included — padded rows run the same graph) for ``capacity`` timesteps,
     ``shards``-way data-parallel.  ``rows`` may be fractional: with early

@@ -16,8 +16,15 @@ from __future__ import annotations
 import dataclasses
 
 from repro.dse.fpga_model import RNNArch
-from repro.launch.analysis import HBM_BW, ICI_BW, PEAK_FLOPS, active_params
+from repro.launch import analysis
+from repro.launch.analysis import active_params
 from repro.models.config import ArchConfig, ShapeCell
+
+
+#: The modeled chip's peaks.  The model prices that chip whatever device
+#: the process runs on; ``repro.dse.calibrate`` maps its seconds onto the
+#: ticks the engine actually observes.
+_PEAKS = analysis.peaks(analysis.MODELED_DEVICE)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -89,10 +96,10 @@ def rnn_step_model(arch: RNNArch, *, batch: float = 1, n_samples: float = 1,
     t_steps = arch.timesteps
     flops = rows * (t_steps * flops_step + flops_head)
     bytes_hbm = weight_bytes + rows * t_steps * act_bytes_step
+    t_c, t_m = flops / _PEAKS.flops, bytes_hbm / _PEAKS.hbm_bw
     return {"flops": flops, "bytes": bytes_hbm, "coll": 0.0,
-            "t_compute": flops / PEAK_FLOPS, "t_memory": bytes_hbm / HBM_BW,
-            "t_collective": 0.0,
-            "t_step": max(flops / PEAK_FLOPS, bytes_hbm / HBM_BW)}
+            "t_compute": t_c, "t_memory": t_m, "t_collective": 0.0,
+            "t_step": max(t_c, t_m)}
 
 
 def rnn_latency_s(arch: RNNArch, hw=None, batch: int = 1,
@@ -152,11 +159,11 @@ def step_model(cfg: ArchConfig, cell: ShapeCell, hw: TpuHwConfig) -> dict:
         cache = _cache_bytes(cfg, cell.seq_len) * cell.global_batch / hw.chips
         bytes_hbm = weights + cache
         coll = 2 * bsz * D * 2 * 2 * cfg.num_layers
+    t_c, t_m = flops / _PEAKS.flops, bytes_hbm / _PEAKS.hbm_bw
+    t_x = coll / _PEAKS.ici_bw
     return {"flops": flops, "bytes": bytes_hbm, "coll": coll,
-            "t_compute": flops / PEAK_FLOPS, "t_memory": bytes_hbm / HBM_BW,
-            "t_collective": coll / ICI_BW,
-            "t_step": max(flops / PEAK_FLOPS, bytes_hbm / HBM_BW,
-                          coll / ICI_BW)}
+            "t_compute": t_c, "t_memory": t_m, "t_collective": t_x,
+            "t_step": max(t_c, t_m, t_x)}
 
 
 def memory_model(cfg: ArchConfig, cell: ShapeCell, hw: TpuHwConfig) -> float:

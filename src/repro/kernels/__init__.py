@@ -1,8 +1,9 @@
 """Pallas TPU kernels for the paper's compute hot spots.
 
 Each kernel <name>.py carries explicit BlockSpec VMEM tiling; ops.py holds
-the jit'd wrappers; ref.py the pure-jnp oracles the tests assert against
-(interpret=True on CPU; native lowering on TPU).
+the jit'd wrappers; ref.py the pure-jnp oracles the tests assert against.
+Every kernel's ``interpret`` defaults to :func:`resolve_interpret`: native
+lowering on a TPU backend, the Pallas interpreter anywhere else (CPU tests).
 
   bernoulli_mask  counter-PRNG mask generate+apply (the paper's LFSR + DX)
   mcd_matmul      fused MCD mask + matmul (K-tiled, fp32 VMEM accumulator)
@@ -15,6 +16,21 @@ the jit'd wrappers; ref.py the pure-jnp oracles the tests assert against
                   path — packed codes + scales dequantized in-register by
                   the sequence kernels (the ``precision`` knob)
 
-compat.py shims Pallas/sharding API names across jax releases; ops.py exposes
-the ``LSTM_BACKENDS`` dispatch consumed by ``repro.core.rnn.run_stack``.
+ops.py exposes the ``LSTM_BACKENDS`` dispatch consumed by
+``repro.core.rnn.run_stack``.
 """
+
+from __future__ import annotations
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """A kernel's ``interpret`` argument: None interprets unless JAX's
+    default backend is a TPU.
+
+    On a TPU the kernels lower natively unless a caller asks for the
+    interpreter by name, so no path there interprets silently.
+    """
+    if interpret is None:
+        import jax
+        return jax.default_backend() != "tpu"
+    return bool(interpret)

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core import prng
+from repro.kernels import resolve_interpret
 
 
 def _kernel(rows_ref, key_ref, x_ref, o_ref, *, p_drop: float, n_feat: int,
@@ -40,7 +41,8 @@ def _kernel(rows_ref, key_ref, x_ref, o_ref, *, p_drop: float, n_feat: int,
                                              "interpret"))
 def masked_activation(x: jax.Array, rows: jax.Array, key: jax.Array,
                       p_drop: float, *, block_b: int = 256,
-                      block_f: int = 512, interpret: bool = True) -> jax.Array:
+                      block_f: int = 512,
+                      interpret: bool | None = None) -> jax.Array:
     """x: [B, F] activations → x ⊙ z / (1-p) with z ~ Bern(1-p) per (row, f)."""
     B, F = x.shape
     bb, bf = min(block_b, B), min(block_f, F)
@@ -57,5 +59,5 @@ def masked_activation(x: jax.Array, rows: jax.Array, key: jax.Array,
         ],
         out_specs=pl.BlockSpec((bb, bf), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((B, F), x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(rows2, key2, x)

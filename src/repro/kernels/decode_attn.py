@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
+from repro.kernels import resolve_interpret
 
 
 def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
@@ -62,7 +62,7 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                      pos: jax.Array, *, block_s: int = 512,
-                     interpret: bool = True) -> jax.Array:
+                     interpret: bool | None = None) -> jax.Array:
     """q: [B, H, hd] (post-RoPE); caches: [B, S, KV, hd]; pos: scalar.
 
     Returns [B, H, hd] attention output (softmax over positions ≤ pos).
@@ -92,6 +92,7 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
             pltpu.VMEM((KV, rep), jnp.float32),
             pltpu.VMEM((KV, rep, hd), jnp.float32),
         ],
-        compiler_params=compat.compiler_params("parallel", "arbitrary"),
-        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=resolve_interpret(interpret),
     )(pos2, q, k_cache, v_cache)

@@ -30,9 +30,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import mcd
-from repro.kernels import compat
+from repro.kernels import resolve_interpret
 from repro.kernels.mcd_lstm import _gate_mask
 
 
@@ -96,7 +97,7 @@ def gate_keys(seed, layer) -> jax.Array:
 def mcd_gru_step(x: jax.Array, h: jax.Array, wx: jax.Array, wh: jax.Array,
                  b: jax.Array, rows: jax.Array, keys: jax.Array,
                  p_drop: float, *, block_b: int = 128, block_h: int = 256,
-                 interpret: bool = True):
+                 interpret: bool | None = None):
     """Fused Bayesian GRU step.
 
     x: [B, I]; h: [B, H]; wx: [I, 3, H]; wh: [H, 3, H]; b: [3, H];
@@ -129,7 +130,8 @@ def mcd_gru_step(x: jax.Array, h: jax.Array, wx: jax.Array, wh: jax.Array,
         ],
         out_specs=pl.BlockSpec((bb, bh), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Bp, H), h.dtype),
-        compiler_params=compat.compiler_params("parallel", "parallel"),
-        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=resolve_interpret(interpret),
     )(rows2, keys, x, h, h, wx, wh, b)
     return out[:B] if pad else out

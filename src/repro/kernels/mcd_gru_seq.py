@@ -2,16 +2,19 @@
 
 The GRU counterpart of :mod:`repro.kernels.mcd_lstm_seq` — same residency
 story (weights fetched into VMEM once, the sequence streams through the
-resident datapath), same streaming contract, one structural difference: the
-GRU's entire recurrent state is ``h``, so there is a single VMEM scratch
-carry and a single carried-state operand.
+resident datapath), same time-major layout and time blocking, same
+streaming contract, one structural difference: the GRU's entire recurrent
+state is ``h``, so there is a single VMEM scratch carry and a single
+carried-state operand.
 
-* Grid ``(B/bb, T)`` with time as an ``"arbitrary"`` (sequential) dimension;
-  the weight BlockSpecs map every grid step to the same block so
-  ``wx [I,3,H]`` / ``wh [H,3,H]`` are fetched once; only the ``[bb, 1, I]``
-  input slice streams per step.
-* ``h`` lives in VMEM scratch across grid steps (seeded from ``h0`` at
-  ``t == 0``), stored in the activation dtype each step — exactly the
+* Time-major grid ``(B/bb, T/tb)`` with time as an ``"arbitrary"``
+  (sequential) dimension; each program loops over the ``tb`` steps of its
+  ``[tb, bb, I]`` input block in-kernel (``tb`` from
+  :func:`repro.kernels.mcd_lstm_seq.time_block`).  The weight BlockSpecs map
+  every grid step to the same block so ``wx [I,3,H]`` / ``wh [H,3,H]`` are
+  fetched once.
+* ``h`` lives in VMEM scratch across time blocks (seeded from ``h0`` at the
+  first block), stored in the activation dtype each step — exactly the
   per-step rounding of :func:`repro.core.cells.gru_step`, which is what
   makes a chunk boundary (bf16 ``h`` out, bf16 ``h`` back in) lossless and
   chunked == unchunked bit-identical.  The gate math runs in fp32.
@@ -19,7 +22,8 @@ carry and a single carried-state operand.
   step from the 6 ``gate_keys`` streams; keys carry no time coordinate, so
   recomputation is the paper's tied-across-T semantics.
 * ``lengths`` freezes a row's ``h`` once ``t >= lengths[row]`` (ragged
-  chunks pad to a common T, each row comes back at its own last real step);
+  chunks pad to a common T, each row comes back at its own last real step;
+  a T padded up to the time block freezes at T the same way);
   ``block_b`` pads a non-dividing batch up to the block multiple.
 
 No hidden-tile grid axis, for the same dependency reason as the LSTM
@@ -37,11 +41,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat, quantize
+from repro.kernels import quantize, resolve_interpret
 from repro.kernels.mcd_gru import _gru_update
+from repro.kernels.mcd_lstm_seq import _padded, time_block
 
 
-def _kernel(*refs, p_drop: float, in_dim: int, hidden: int, varlen: bool,
+def _kernel(*refs, p_drop: float, in_dim: int, hidden: int, t_block: int,
             weight_bits: int | None):
     # Quantized runs insert two [3, H] fp32 scale operands after the weights;
     # everything else (ref order, outputs, scratch) is unchanged.
@@ -51,17 +56,15 @@ def _kernel(*refs, p_drop: float, in_dim: int, hidden: int, varlen: bool,
     else:
         (rows_ref, keys_ref, lens_ref, x_ref, h0_ref, wx_ref, wh_ref,
          sx_ref, sh_ref, b_ref, ys_ref, ht_ref, h_scr) = refs
-    t = pl.program_id(1)
+    blk = pl.program_id(1)
 
-    @pl.when(t == 0)
+    @pl.when(blk == 0)
     def _reset():
         # Carried-state entry point: a fresh sequence passes zeros here; a
         # resumed session passes the previous chunk's h_T.
         h_scr[...] = h0_ref[...]
 
     rows = rows_ref[...][:, 0]
-    x = x_ref[:, 0, :]              # [bb, I] — this step's input slice
-    h = h_scr[...]                  # [bb, H] — carried entirely in VMEM
     if weight_bits is None:
         wxv, whv = wx_ref[...], wh_ref[...]
     else:
@@ -69,22 +72,31 @@ def _kernel(*refs, p_drop: float, in_dim: int, hidden: int, varlen: bool,
         # q·scale expression (repro.kernels.quantize), cast to the activation
         # dtype — exactly the values fake_quant hands the other backends.
         wxv = quantize.kernel_weight(wx_ref[...], sx_ref[...], weight_bits,
-                                     hidden=hidden, act_dtype=x.dtype)
+                                     hidden=hidden, act_dtype=x_ref.dtype)
         whv = quantize.kernel_weight(wh_ref[...], sh_ref[...], weight_bits,
-                                     hidden=hidden, act_dtype=x.dtype)
-    # Gate body shared with the step kernel; the keys are t-independent so
-    # recomputing the masks here every step *is* tying them across time.
-    h_new = _gru_update(x, h, h, rows, keys_ref, wxv, whv, b_ref,
-                        p_drop=p_drop, in_dim=in_dim,
-                        hidden=hidden).astype(h_scr.dtype)
-    if varlen:
-        # Rows whose chunk ended before this step keep their carried state —
-        # the final h_T output is each row's state at its own length.
-        live = t < lens_ref[...]                  # [bb, 1]
-        h_new = jnp.where(live, h_new, h_scr[...])
-    h_scr[...] = h_new
-    ys_ref[:, 0, :] = h_new.astype(ys_ref.dtype)
-    ht_ref[...] = h_new.astype(ht_ref.dtype)
+                                     hidden=hidden, act_dtype=x_ref.dtype)
+    t0 = blk * t_block
+
+    def step(s, h):
+        x = x_ref[s]                # [bb, I] — this step's input slice
+        # Gate body shared with the step kernel; the keys are t-independent
+        # so recomputing the masks here every step *is* tying them across
+        # time.
+        h_new = _gru_update(x, h, h, rows, keys_ref, wxv, whv, b_ref,
+                            p_drop=p_drop, in_dim=in_dim,
+                            hidden=hidden).astype(h.dtype)
+        # Rows whose chunk ended before this step keep their carried state
+        # — the final h_T output is each row's state at its own length.
+        # Unconditional, so a launch with and without ``lengths`` runs the
+        # same ops and rounds the same way.
+        live = t0 + s < lens_ref[...]              # [bb, 1]
+        h_new = jnp.where(live, h_new, h)
+        ys_ref[s] = h_new.astype(ys_ref.dtype)
+        return h_new
+
+    h = jax.lax.fori_loop(0, t_block, step, h_scr[...])
+    h_scr[...] = h
+    ht_ref[...] = h.astype(ht_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("p_drop", "block_b", "interpret",
@@ -96,7 +108,7 @@ def mcd_gru_seq(x_seq: jax.Array, wx: jax.Array, wh: jax.Array, b: jax.Array,
                 weight_bits: int | None = None,
                 wx_scale: jax.Array | None = None,
                 wh_scale: jax.Array | None = None,
-                block_b: int = 128, interpret: bool = True):
+                block_b: int = 128, interpret: bool | None = None):
     """Sequence-fused Bayesian GRU layer, optionally resuming carried state.
 
     x_seq: [B, T, I]; wx: [I, 3, H]; wh: [H, 3, H]; b: [3, H];
@@ -111,6 +123,8 @@ def mcd_gru_seq(x_seq: jax.Array, wx: jax.Array, wh: jax.Array, b: jax.Array,
     ``wx_scale``/``wh_scale`` the [3, H] fp32 per-output-channel scales; the
     kernel dequantizes in-register, so the VMEM-resident weight bytes drop
     ~2×/4× vs bf16 while the gate math stays fp32-accumulated.
+    ``interpret`` None runs natively on a TPU backend and in the Pallas
+    interpreter elsewhere (:func:`repro.kernels.resolve_interpret`).
     Returns (ys [B, T, H], h_T [B, H]); with ``lengths``, h_T is each row's
     state at ``t = lengths[row]`` and ``ys[:, t >= lengths[row]]`` repeats
     the frozen h.
@@ -120,18 +134,22 @@ def mcd_gru_seq(x_seq: jax.Array, wx: jax.Array, wh: jax.Array, b: jax.Array,
     if weight_bits is not None and (wx_scale is None or wh_scale is None):
         raise ValueError("weight_bits set but wx_scale/wh_scale missing")
     bb = min(block_b, B)
-    varlen = lengths is not None
+    tb = time_block(T, bb, I, H, x_seq.dtype.itemsize)
+    Tp = _padded(T, tb)
     h0 = jnp.zeros((B, H), x_seq.dtype) if h0 is None else h0.astype(x_seq.dtype)
     lens = (jnp.full((B,), T, jnp.int32) if lengths is None
             else lengths.astype(jnp.int32))
     rows2 = rows.astype(jnp.int32).reshape(B, 1)
+    xt = jnp.swapaxes(x_seq, 0, 1)       # [T, B, I] time-major
     pad = -B % bb        # pad to the block multiple (prime/odd batch sizes)
     if pad:
         zb = lambda a: jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
-        x_seq, rows2, h0, lens = map(zb, (x_seq, rows2, h0, lens))
+        rows2, h0, lens = map(zb, (rows2, h0, lens))
+    if pad or Tp != T:
+        xt = jnp.pad(xt, ((0, Tp - T), (0, pad), (0, 0)))
     Bp = B + pad
     lens2 = lens.reshape(Bp, 1)
-    grid = (Bp // bb, T)
+    grid = (Bp // bb, Tp // tb)
     Wl = wx.shape[-1]    # H, or ceil(H/2) when int4 nibble-packed
     w_specs = [
         pl.BlockSpec((I, 3, Wl), lambda i, t: (0, 0, 0)),      # wx — resident
@@ -144,31 +162,31 @@ def mcd_gru_seq(x_seq: jax.Array, wx: jax.Array, wh: jax.Array, b: jax.Array,
         w_ops += (wx_scale, wh_scale)
     ys, hT = pl.pallas_call(
         functools.partial(_kernel, p_drop=p_drop, in_dim=I, hidden=H,
-                          varlen=varlen, weight_bits=weight_bits),
+                          t_block=tb, weight_bits=weight_bits),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bb, 1), lambda i, t: (i, 0)),        # rows
-            pl.BlockSpec((1, 6), lambda i, t: (0, 0)),         # keys
-            pl.BlockSpec((bb, 1), lambda i, t: (i, 0)),        # lengths
-            pl.BlockSpec((bb, 1, I), lambda i, t: (i, t, 0)),  # x_t slice
-            pl.BlockSpec((bb, H), lambda i, t: (i, 0)),        # h0
+            pl.BlockSpec((bb, 1), lambda i, t: (i, 0)),         # rows
+            pl.BlockSpec((1, 6), lambda i, t: (0, 0)),          # keys
+            pl.BlockSpec((bb, 1), lambda i, t: (i, 0)),         # lengths
+            pl.BlockSpec((tb, bb, I), lambda i, t: (t, i, 0)),  # x time block
+            pl.BlockSpec((bb, H), lambda i, t: (i, 0)),         # h0
             *w_specs,
-            pl.BlockSpec((3, H), lambda i, t: (0, 0)),         # bias
+            pl.BlockSpec((3, H), lambda i, t: (0, 0)),          # bias
         ],
         out_specs=[
-            pl.BlockSpec((bb, 1, H), lambda i, t: (i, t, 0)),
+            pl.BlockSpec((tb, bb, H), lambda i, t: (t, i, 0)),
             pl.BlockSpec((bb, H), lambda i, t: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Bp, T, H), x_seq.dtype),
+            jax.ShapeDtypeStruct((Tp, Bp, H), x_seq.dtype),
             jax.ShapeDtypeStruct((Bp, H), x_seq.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bb, H), x_seq.dtype),    # h carry — the whole state
         ],
-        compiler_params=compat.compiler_params("parallel", "arbitrary"),
-        interpret=interpret,
-    )(rows2, keys, lens2, x_seq, h0, *w_ops, b)
-    if pad:
-        ys, hT = ys[:B], hT[:B]
-    return ys, hT
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=resolve_interpret(interpret),
+    )(rows2, keys, lens2, xt, h0, *w_ops, b)
+    ys = jnp.swapaxes(ys[:T, :B], 0, 1)
+    return ys, hT[:B]

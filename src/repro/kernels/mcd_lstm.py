@@ -26,7 +26,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import mcd, prng
-from repro.kernels import compat
+from repro.kernels import resolve_interpret
 
 
 def _gate_mask(key, rows, cols0, shape, feat_dim: int, p_drop: float):
@@ -82,7 +82,7 @@ def gate_keys(seed, layer) -> jax.Array:
 def mcd_lstm_step(x: jax.Array, h: jax.Array, c: jax.Array, wx: jax.Array,
                   wh: jax.Array, b: jax.Array, rows: jax.Array,
                   keys: jax.Array, p_drop: float, *, block_b: int = 128,
-                  block_h: int = 256, interpret: bool = True):
+                  block_h: int = 256, interpret: bool | None = None):
     """Fused Bayesian LSTM step.
 
     x: [B, I]; h, c: [B, H]; wx: [I, 4, H]; wh: [H, 4, H]; b: [4, H];
@@ -121,8 +121,9 @@ def mcd_lstm_step(x: jax.Array, h: jax.Array, c: jax.Array, wx: jax.Array,
             jax.ShapeDtypeStruct((Bp, H), h.dtype),
             jax.ShapeDtypeStruct((Bp, H), c.dtype),
         ],
-        compiler_params=compat.compiler_params("parallel", "parallel"),
-        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=resolve_interpret(interpret),
     )(rows2, keys, x, h, c, wx, wh, b)
     if pad:
         out = [o[:B] for o in out]

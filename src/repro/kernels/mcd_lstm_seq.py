@@ -6,12 +6,19 @@ gate weights every iteration — exactly the weight-traffic the paper's FPGA
 avoids by keeping the datapath resident while the sequence streams through
 (wave pipelining).  This kernel is the TPU analogue of that residency:
 
-* Grid ``(B/bb, T)`` with time as an ``"arbitrary"`` (sequential) dimension.
-  The weight BlockSpecs map every grid step to the same block, so Pallas's
+* The sequence runs **time-major** (``[T, B, I]``) over the grid
+  ``(B/bb, T/tb)``, time an ``"arbitrary"`` (sequential) dimension.  Each
+  program takes a ``[tb, bb, I]`` block of ``tb`` timesteps and loops over
+  them in-kernel, writing a ``[tb, bb, H]`` output block.  The minor two
+  block dims are ``(bb, I)`` / ``(bb, H)`` — batch rows on sublanes, features
+  on lanes — which the TPU's (8, 128) block rule accepts; the time axis is
+  a leading dim with no tiling constraint, so ``tb`` is sized from a VMEM
+  budget (:func:`time_block`) rather than from alignment.
+* The weight BlockSpecs map every grid step to the same block, so Pallas's
   revisiting semantics fetch ``wx [I,4,H]`` / ``wh [H,4,H]`` into VMEM
-  **once**; only the ``[bb, 1, I]`` input slice streams per step.
-* ``(h, c)`` live in VMEM scratch across grid steps (reset at ``t == 0``),
-  with ``c`` in fp32 — the paper's 32-bit cell-state policy.
+  **once**; only the input blocks stream.
+* ``(h, c)`` live in VMEM scratch across time blocks (seeded at the first
+  block), with ``c`` in fp32 — the paper's 32-bit cell-state policy.
 * The per-gate Bernoulli keep-masks are recomputed in-register each step from
   the counter PRNG.  Masks are tied across T (paper §II-B), so the 8 stream
   keys from :func:`repro.kernels.mcd_lstm.gate_keys` never change and every
@@ -28,13 +35,15 @@ VMEM in bf16).
 
 Streaming extensions (continuous-monitoring serving):
 
-* ``h0`` / ``c0`` seed the scratch at ``t == 0`` instead of zeros, so a
-  session resumes mid-sequence exactly where a previous chunk left off.
+* ``h0`` / ``c0`` seed the scratch at the first time block instead of zeros,
+  so a session resumes mid-sequence exactly where a previous chunk left off.
   ``c0`` is consumed in fp32 — the fp32 cell state round-trips losslessly
   across chunk boundaries, keeping chunked == unchunked bit-identical.
 * ``lengths`` freezes a row's ``(h, c)`` once ``t >= lengths[row]``: ragged
   chunks from concurrent sessions pad to a common T and still come back with
-  each row's state at *its own* last real step, in one launch.
+  each row's state at *its own* last real step, in one launch.  A T that is
+  not a multiple of ``tb`` pads up to one and freezes every row at T the
+  same way.
 * A ``block_b`` that does not divide B pads the batch up to the next block
   multiple (outputs sliced back) instead of degrading to ``bb = 1`` for prime
   batch sizes.
@@ -49,12 +58,42 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat, quantize
+from repro.kernels import quantize, resolve_interpret
 from repro.kernels.mcd_lstm import _gate_mask
+
+#: VMEM bytes the streamed time blocks (input + output, double-buffered) may
+#: take.  With the resident weights beside them this keeps every launch of
+#: the scheduler's ladder (T up to 512, H up to a few hundred) under the
+#: compiler's default scoped-VMEM limit (16 MiB on v5e).
+STREAM_VMEM_BYTES = 4 << 20
+
+
+def _padded(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def time_block(T: int, bb: int, in_dim: int, hidden: int,
+               itemsize: int) -> int:
+    """Timesteps per grid program for a ``[T, bb, *]`` time-major launch.
+
+    A VMEM tile pads its minor dim to 128 lanes and its second-minor to 8
+    sublanes, so one timestep of the ``[bb, I]`` input and ``[bb, H]``
+    output blocks costs ``bb·(⌈I⌉₁₂₈ + ⌈H⌉₁₂₈)·itemsize`` bytes, twice for
+    double buffering — at the paper's I=1, H=8 that is 256 lanes for 9
+    useful ones.  ``tb`` is the largest step count within
+    :data:`STREAM_VMEM_BYTES`, evened out over the blocks T needs so that
+    the padded tail is as short as possible (T=140 at bb=128 fp32: 9
+    blocks of 16, 4 padded steps).
+    """
+    per_step = (2 * _padded(bb, 8) * (_padded(in_dim, 128)
+                                       + _padded(hidden, 128)) * itemsize)
+    cap = max(1, STREAM_VMEM_BYTES // per_step)
+    n_blocks = -(-T // cap)
+    return -(-T // n_blocks)
 
 
 def _kernel(*refs,
-            p_drop: float, in_dim: int, hidden: int, varlen: bool,
+            p_drop: float, in_dim: int, hidden: int, t_block: int,
             weight_bits: int | None):
     # Quantized runs insert two [4, H] fp32 scale operands after the weights;
     # everything else (ref order, outputs, scratch) is unchanged.
@@ -66,9 +105,9 @@ def _kernel(*refs,
         (rows_ref, keys_ref, lens_ref, x_ref, h0_ref, c0_ref,
          wx_ref, wh_ref, sx_ref, sh_ref, b_ref,
          ys_ref, ht_ref, ct_ref, h_scr, c_scr) = refs
-    t = pl.program_id(1)
+    blk = pl.program_id(1)
 
-    @pl.when(t == 0)
+    @pl.when(blk == 0)
     def _reset():
         # Carried-state entry point: a fresh sequence passes zeros here; a
         # resumed session passes the previous chunk's (h_T, c_T).
@@ -76,8 +115,7 @@ def _kernel(*refs,
         c_scr[...] = c0_ref[...]
 
     rows = rows_ref[...][:, 0]
-    x = x_ref[:, 0, :]              # [bb, I] — this step's input slice
-    h = h_scr[...]                  # [bb, H] — carried entirely in VMEM
+    act = x_ref.dtype
     if weight_bits is None:
         wxv, whv = wx_ref[...], wh_ref[...]
     else:
@@ -85,47 +123,58 @@ def _kernel(*refs,
         # q·scale expression (repro.kernels.quantize), cast to the activation
         # dtype — exactly the values fake_quant hands the other backends.
         wxv = quantize.kernel_weight(wx_ref[...], sx_ref[...], weight_bits,
-                                     hidden=hidden, act_dtype=x.dtype)
+                                     hidden=hidden, act_dtype=act)
         whv = quantize.kernel_weight(wh_ref[...], sh_ref[...], weight_bits,
-                                     hidden=hidden, act_dtype=x.dtype)
-    gates = []
+                                     hidden=hidden, act_dtype=act)
     # int32 rows: a negative id carries mcd.STUDENT_ROW_FLAG — that row runs
     # deterministic (dropout off), co-batched with the Bayesian rows.
     det = (rows < 0)[:, None]
-    scale = jnp.asarray(1.0 / (1.0 - p_drop), x.dtype) if p_drop > 0 else None
-    for g in range(4):
-        xg, hg = x, h
-        if p_drop > 0.0:
-            # Same (key, row, col) → bit mapping as the step kernel; keys are
-            # t-independent so recomputing here *is* tying across time.
-            kx = keys_ref[0, g]
-            kh = keys_ref[0, 4 + g]
-            mx = _gate_mask(kx, rows, 0, x.shape, in_dim, p_drop)
-            mh = _gate_mask(kh, rows, 0, h.shape, hidden, p_drop)
-            xg = jnp.where(mx, x * scale, jnp.zeros_like(x))
-            hg = jnp.where(mh, h * scale, jnp.zeros_like(h))
-            xg = jnp.where(det, x, xg)
-            hg = jnp.where(det, h, hg)
-        acc = jnp.dot(xg, wxv[:, g, :], preferred_element_type=jnp.float32)
-        acc += jnp.dot(hg, whv[:, g, :], preferred_element_type=jnp.float32)
-        gates.append(acc + b_ref[g, :].astype(jnp.float32))
-    i = jax.nn.sigmoid(gates[0])
-    f = jax.nn.sigmoid(gates[1])
-    g_ = jnp.tanh(gates[2])
-    o = jax.nn.sigmoid(gates[3])
-    c_new = f * c_scr[...] + i * g_
-    h_new = (o * jnp.tanh(c_new)).astype(h_scr.dtype)
-    if varlen:
-        # Rows whose chunk ended before this step keep their carried state —
-        # the final (h_T, c_T) outputs are each row's state at its own length.
-        live = t < lens_ref[...]                  # [bb, 1]
-        c_new = jnp.where(live, c_new, c_scr[...])
-        h_new = jnp.where(live, h_new, h_scr[...])
-    c_scr[...] = c_new
-    h_scr[...] = h_new
-    ys_ref[:, 0, :] = h_new.astype(ys_ref.dtype)
-    ht_ref[...] = h_new.astype(ht_ref.dtype)
-    ct_ref[...] = c_new.astype(ct_ref.dtype)
+    scale = jnp.asarray(1.0 / (1.0 - p_drop), act) if p_drop > 0 else None
+    t0 = blk * t_block
+
+    def step(s, carry):
+        h, c = carry                # [bb, H] act dtype / fp32
+        x = x_ref[s]                # [bb, I] — this step's input slice
+        gates = []
+        for g in range(4):
+            xg, hg = x, h
+            if p_drop > 0.0:
+                # Same (key, row, col) → bit mapping as the step kernel; keys
+                # are t-independent so recomputing here *is* tying across
+                # time.
+                kx = keys_ref[0, g]
+                kh = keys_ref[0, 4 + g]
+                mx = _gate_mask(kx, rows, 0, x.shape, in_dim, p_drop)
+                mh = _gate_mask(kh, rows, 0, h.shape, hidden, p_drop)
+                xg = jnp.where(mx, x * scale, jnp.zeros_like(x))
+                hg = jnp.where(mh, h * scale, jnp.zeros_like(h))
+                xg = jnp.where(det, x, xg)
+                hg = jnp.where(det, h, hg)
+            acc = jnp.dot(xg, wxv[:, g, :], preferred_element_type=jnp.float32)
+            acc += jnp.dot(hg, whv[:, g, :],
+                           preferred_element_type=jnp.float32)
+            gates.append(acc + b_ref[g, :].astype(jnp.float32))
+        i = jax.nn.sigmoid(gates[0])
+        f = jax.nn.sigmoid(gates[1])
+        g_ = jnp.tanh(gates[2])
+        o = jax.nn.sigmoid(gates[3])
+        c_new = f * c + i * g_
+        h_new = (o * jnp.tanh(c_new)).astype(h.dtype)
+        # Rows whose chunk ended before this step keep their carried state
+        # — the final (h_T, c_T) are each row's state at its own length.
+        # Unconditional, so a launch with and without ``lengths`` runs the
+        # same ops and rounds the same way.
+        live = t0 + s < lens_ref[...]              # [bb, 1]
+        c_new = jnp.where(live, c_new, c)
+        h_new = jnp.where(live, h_new, h)
+        ys_ref[s] = h_new.astype(ys_ref.dtype)
+        return h_new, c_new
+
+    h, c = jax.lax.fori_loop(0, t_block, step, (h_scr[...], c_scr[...]))
+    h_scr[...] = h
+    c_scr[...] = c
+    ht_ref[...] = h.astype(ht_ref.dtype)
+    ct_ref[...] = c.astype(ct_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("p_drop", "block_b", "interpret",
@@ -137,7 +186,7 @@ def mcd_lstm_seq(x_seq: jax.Array, wx: jax.Array, wh: jax.Array, b: jax.Array,
                  weight_bits: int | None = None,
                  wx_scale: jax.Array | None = None,
                  wh_scale: jax.Array | None = None,
-                 block_b: int = 128, interpret: bool = True):
+                 block_b: int = 128, interpret: bool | None = None):
     """Sequence-fused Bayesian LSTM layer, optionally resuming carried state.
 
     x_seq: [B, T, I]; wx: [I, 4, H]; wh: [H, 4, H]; b: [4, H];
@@ -152,6 +201,8 @@ def mcd_lstm_seq(x_seq: jax.Array, wx: jax.Array, wh: jax.Array, b: jax.Array,
     ``wx_scale``/``wh_scale`` the [4, H] fp32 per-output-channel scales; the
     kernel dequantizes in-register, so the VMEM-resident weight bytes drop
     ~2×/4× vs bf16 while the gate math stays fp32-accumulated.
+    ``interpret`` None runs natively on a TPU backend and in the Pallas
+    interpreter elsewhere (:func:`repro.kernels.resolve_interpret`).
     Returns (ys [B, T, H], h_T [B, H], c_T [B, H] fp32); with ``lengths``,
     (h_T, c_T) is each row's state at ``t = lengths[row]`` and
     ``ys[:, t >= lengths[row]]`` repeats the frozen h.
@@ -161,20 +212,24 @@ def mcd_lstm_seq(x_seq: jax.Array, wx: jax.Array, wh: jax.Array, b: jax.Array,
     if weight_bits is not None and (wx_scale is None or wh_scale is None):
         raise ValueError("weight_bits set but wx_scale/wh_scale missing")
     bb = min(block_b, B)
-    varlen = lengths is not None
+    tb = time_block(T, bb, I, H, x_seq.dtype.itemsize)
+    Tp = _padded(T, tb)
     h0 = jnp.zeros((B, H), x_seq.dtype) if h0 is None else h0.astype(x_seq.dtype)
     c0 = (jnp.zeros((B, H), jnp.float32) if c0 is None
           else c0.astype(jnp.float32))
     lens = (jnp.full((B,), T, jnp.int32) if lengths is None
             else lengths.astype(jnp.int32))
     rows2 = rows.astype(jnp.int32).reshape(B, 1)
+    xt = jnp.swapaxes(x_seq, 0, 1)       # [T, B, I] time-major
     pad = -B % bb        # pad to the block multiple (prime/odd batch sizes)
     if pad:
         zb = lambda a: jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
-        x_seq, rows2, h0, c0, lens = map(zb, (x_seq, rows2, h0, c0, lens))
+        rows2, h0, c0, lens = map(zb, (rows2, h0, c0, lens))
+    if pad or Tp != T:
+        xt = jnp.pad(xt, ((0, Tp - T), (0, pad), (0, 0)))
     Bp = B + pad
     lens2 = lens.reshape(Bp, 1)
-    grid = (Bp // bb, T)
+    grid = (Bp // bb, Tp // tb)
     Wl = wx.shape[-1]    # H, or ceil(H/2) when int4 nibble-packed
     w_specs = [
         pl.BlockSpec((I, 4, Wl), lambda i, t: (0, 0, 0)),      # wx — resident
@@ -187,25 +242,25 @@ def mcd_lstm_seq(x_seq: jax.Array, wx: jax.Array, wh: jax.Array, b: jax.Array,
         w_ops += (wx_scale, wh_scale)
     ys, hT, cT = pl.pallas_call(
         functools.partial(_kernel, p_drop=p_drop, in_dim=I, hidden=H,
-                          varlen=varlen, weight_bits=weight_bits),
+                          t_block=tb, weight_bits=weight_bits),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bb, 1), lambda i, t: (i, 0)),        # rows
-            pl.BlockSpec((1, 8), lambda i, t: (0, 0)),         # keys
-            pl.BlockSpec((bb, 1), lambda i, t: (i, 0)),        # lengths
-            pl.BlockSpec((bb, 1, I), lambda i, t: (i, t, 0)),  # x_t slice
-            pl.BlockSpec((bb, H), lambda i, t: (i, 0)),        # h0
-            pl.BlockSpec((bb, H), lambda i, t: (i, 0)),        # c0 (fp32)
+            pl.BlockSpec((bb, 1), lambda i, t: (i, 0)),         # rows
+            pl.BlockSpec((1, 8), lambda i, t: (0, 0)),          # keys
+            pl.BlockSpec((bb, 1), lambda i, t: (i, 0)),         # lengths
+            pl.BlockSpec((tb, bb, I), lambda i, t: (t, i, 0)),  # x time block
+            pl.BlockSpec((bb, H), lambda i, t: (i, 0)),         # h0
+            pl.BlockSpec((bb, H), lambda i, t: (i, 0)),         # c0 (fp32)
             *w_specs,
-            pl.BlockSpec((4, H), lambda i, t: (0, 0)),         # bias
+            pl.BlockSpec((4, H), lambda i, t: (0, 0)),          # bias
         ],
         out_specs=[
-            pl.BlockSpec((bb, 1, H), lambda i, t: (i, t, 0)),
+            pl.BlockSpec((tb, bb, H), lambda i, t: (t, i, 0)),
             pl.BlockSpec((bb, H), lambda i, t: (i, 0)),
             pl.BlockSpec((bb, H), lambda i, t: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Bp, T, H), x_seq.dtype),
+            jax.ShapeDtypeStruct((Tp, Bp, H), x_seq.dtype),
             jax.ShapeDtypeStruct((Bp, H), x_seq.dtype),
             jax.ShapeDtypeStruct((Bp, H), jnp.float32),
         ],
@@ -213,9 +268,9 @@ def mcd_lstm_seq(x_seq: jax.Array, wx: jax.Array, wh: jax.Array, b: jax.Array,
             pltpu.VMEM((bb, H), x_seq.dtype),    # h carry
             pltpu.VMEM((bb, H), jnp.float32),    # c carry (32-bit policy)
         ],
-        compiler_params=compat.compiler_params("parallel", "arbitrary"),
-        interpret=interpret,
-    )(rows2, keys, lens2, x_seq, h0, c0, *w_ops, b)
-    if pad:
-        ys, hT, cT = ys[:B], hT[:B], cT[:B]
-    return ys, hT, cT
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=resolve_interpret(interpret),
+    )(rows2, keys, lens2, xt, h0, c0, *w_ops, b)
+    ys = jnp.swapaxes(ys[:T, :B], 0, 1)
+    return ys, hT[:B], cT[:B]

@@ -17,7 +17,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import prng
-from repro.kernels import compat
+from repro.kernels import resolve_interpret
 
 
 def _kernel(rows_ref, key_ref, x_ref, w_ref, o_ref, acc_ref, *,
@@ -51,7 +51,7 @@ def _kernel(rows_ref, key_ref, x_ref, w_ref, o_ref, acc_ref, *,
                                              "block_k", "interpret"))
 def mcd_matmul(x: jax.Array, w: jax.Array, rows: jax.Array, key: jax.Array,
                p_drop: float, *, block_m: int = 256, block_n: int = 256,
-               block_k: int = 512, interpret: bool = True) -> jax.Array:
+               block_k: int = 512, interpret: bool | None = None) -> jax.Array:
     """x: [M, K], w: [K, N], rows: [M] → [M, N] (fp32-accumulated)."""
     M, K = x.shape
     K2, N = w.shape
@@ -74,6 +74,7 @@ def mcd_matmul(x: jax.Array, w: jax.Array, rows: jax.Array, key: jax.Array,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=compat.compiler_params("parallel", "parallel", "arbitrary"),
-        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=resolve_interpret(interpret),
     )(rows2, key2, x, w)

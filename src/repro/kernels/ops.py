@@ -1,9 +1,10 @@
 """jit'd high-level wrappers around the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (CPU validation path); on a real TPU
-backend the kernels compile natively.  The framework's model code uses the
-pure-jnp mirrors by default (sharding-friendly under GSPMD); these wrappers
-are the TPU hot-path entry points and the unit under test in
+``interpret`` passes through to the kernels, where None resolves to native
+lowering on a TPU backend and the interpreter elsewhere (CPU validation
+path; :func:`repro.kernels.resolve_interpret`).  The framework's model
+code uses the pure-jnp mirrors by default (sharding-friendly under GSPMD);
+these wrappers are the TPU hot-path entry points and the unit under test in
 ``tests/test_kernels.py``.
 """
 
@@ -26,19 +27,10 @@ from repro.kernels import (bernoulli_mask, mcd_gru, mcd_gru_seq, mcd_lstm,
 LSTM_BACKENDS = ("reference", "pallas_step", "pallas_seq")
 
 
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def default_interpret() -> bool:
-    return not on_tpu()
-
-
 def flash_decode_attention(q: jax.Array, k_cache: jax.Array,
                            v_cache: jax.Array, pos, **kw) -> jax.Array:
     """Fused decode attention (EXPERIMENTS.md §Perf Cell C hot path)."""
     from repro.kernels import decode_attn
-    kw.setdefault("interpret", default_interpret())
     return decode_attn.decode_attention(q, k_cache, v_cache, pos, **kw)
 
 
@@ -46,14 +38,12 @@ def mcd_dense(x: jax.Array, w: jax.Array, rows: jax.Array, seed, layer: int,
               site: int, p_drop: float, **kw) -> jax.Array:
     """Fused masked dense: y = (x ⊙ z/(1-p)) @ W with the site-keyed stream."""
     key = mcd.mask_key(seed, layer, mcd.KIND_FEAT, site)
-    kw.setdefault("interpret", default_interpret())
     return mcd_matmul.mcd_matmul(x, w, rows, key, p_drop, **kw)
 
 
 def mcd_mask_apply(x: jax.Array, rows: jax.Array, seed, layer: int, site: int,
                    p_drop: float, **kw) -> jax.Array:
     key = mcd.mask_key(seed, layer, mcd.KIND_FEAT, site)
-    kw.setdefault("interpret", default_interpret())
     return bernoulli_mask.masked_activation(x, rows, key, p_drop, **kw)
 
 
@@ -71,8 +61,6 @@ def fused_lstm_layer(wx4: jax.Array, wh4: jax.Array, b: jax.Array,
     freezes each row's state at its own chunk length (ragged batching).
     Returns (outputs [B, T, H], (h_T, c_T fp32)).
     """
-    if interpret is None:
-        interpret = default_interpret()
     B, T, _ = x_seq.shape
     H = wh4.shape[0]
     keys = mcd_lstm.gate_keys(seed, layer)
@@ -117,8 +105,6 @@ def fused_lstm_seq(wx4: jax.Array, wh4: jax.Array, b: jax.Array,
     With ``weight_bits`` 8/4, ``wx4``/``wh4`` carry quantized codes and
     ``wx_scale``/``wh_scale`` the [4, H] fp32 scales (dequant in-register).
     """
-    if interpret is None:
-        interpret = default_interpret()
     keys = mcd_lstm.gate_keys(seed, layer)
     ys, hT, cT = mcd_lstm_seq.mcd_lstm_seq(x_seq, wx4, wh4, b, rows, keys,
                                            p_drop, h0=h0, c0=c0,
@@ -200,8 +186,6 @@ def fused_gru_layer(wx3: jax.Array, wh3: jax.Array, b: jax.Array,
     Returns (outputs [B, T, H], (h_T,)) — the carry is a 1-tuple because the
     GRU's entire recurrent state is ``h``.
     """
-    if interpret is None:
-        interpret = default_interpret()
     B, T, _ = x_seq.shape
     H = wh3.shape[0]
     keys = mcd_gru.gate_keys(seed, layer)
@@ -238,8 +222,6 @@ def fused_gru_seq(wx3: jax.Array, wh3: jax.Array, b: jax.Array,
     ``wx3``/``wh3`` carry quantized codes and ``wx_scale``/``wh_scale`` the
     [3, H] fp32 scales (dequant in-register).
     """
-    if interpret is None:
-        interpret = default_interpret()
     keys = mcd_gru.gate_keys(seed, layer)
     ys, hT = mcd_gru_seq.mcd_gru_seq(x_seq, wx3, wh3, b, rows, keys, p_drop,
                                      h0=h0, lengths=lengths,

@@ -26,8 +26,9 @@ it):
   jnp expression outside — identical values, so the three backends stay
   bit-identical per precision.
 * **int4 packs two's-complement nibbles** two-per-byte along the last
-  (output/H) axis, padding odd H; ``unpack_int4(pack_int4(q), H) == q``
-  exactly (pinned by ``tests/test_quantize.py``).
+  (output/H) axis — column ``j`` and column ``j + ceil(H/2)`` share a byte
+  — padding odd H; ``unpack_int4(pack_int4(q), H) == q`` exactly (pinned by
+  ``tests/test_quantize.py``).
 * Biases are never quantized — they enter the gate sums in fp32 on every
   path already.
 
@@ -122,28 +123,34 @@ def pack_int4(q: jax.Array) -> jax.Array:
     """Pack int4 codes two-per-byte along the last axis (pad odd lengths).
 
     ``q`` holds values in [-7, 7] (int8); the result is uint8 of length
-    ``ceil(H/2)`` with the even column in the low nibble (two's-complement
-    nibbles — ``-3`` stores as ``0xD``).
+    ``m = ceil(H/2)``: byte ``j`` holds column ``j`` in its low nibble and
+    column ``j + m`` in its high nibble (two's-complement nibbles — ``-3``
+    stores as ``0xD``).  Splitting the axis in halves, rather than
+    interleaving even and odd columns, lets a kernel unpack with one
+    lane-axis concatenation, which Mosaic lowers; an interleave needs a
+    minor-dim reshape that it refuses.
     """
     if q.shape[-1] % 2:
         q = jnp.pad(q, [(0, 0)] * (q.ndim - 1) + [(0, 1)])
+    m = q.shape[-1] // 2
     u = q.astype(jnp.uint8)
-    lo, hi = u[..., 0::2], u[..., 1::2]
-    return (lo & 0xF) | ((hi & 0xF) << 4)
+    return (u[..., :m] & 0xF) | ((u[..., m:] & 0xF) << 4)
+
+
+def _int4_codes(packed: jax.Array, n: int) -> jax.Array:
+    """The int32 codes of :func:`pack_int4` output (kernel-safe arithmetic)."""
+    p = packed.astype(jnp.int32)
+    nib = jnp.concatenate([p & 0xF, (p >> 4) & 0xF], axis=-1)[..., :n]
+    return jnp.where(nib >= 8, nib - 16, nib)
 
 
 def unpack_int4(packed: jax.Array, n: int) -> jax.Array:
     """Invert :func:`pack_int4`: ``[..., ceil(n/2)] uint8 → [..., n] int8``.
 
-    Pure jnp (works identically inside Pallas kernels and in host code);
-    sign-extends each nibble, interleaves low/high and drops the pad
-    column when ``n`` is odd.
+    Sign-extends each nibble, rejoins the low and high halves and drops the
+    pad column when ``n`` is odd.
     """
-    lo = (packed & 0xF).astype(jnp.int8)
-    hi = ((packed >> 4) & 0xF).astype(jnp.int8)
-    nib = jnp.stack([lo, hi], axis=-1).reshape(*packed.shape[:-1], -1)
-    nib = nib[..., :n]
-    return jnp.where(nib >= 8, nib - 16, nib)
+    return _int4_codes(packed, n).astype(jnp.int8)
 
 
 def packed_weight(q: jax.Array, bits: int) -> jax.Array:
@@ -160,7 +167,7 @@ def kernel_weight(w_ref_val: jax.Array, scale: jax.Array, bits: int, *,
     fp32.  Returns the ``[D, G, H]`` activation-dtype weights the gate
     matmuls consume — exactly :func:`fake_quant`'s values.
     """
-    q = unpack_int4(w_ref_val, hidden) if bits == 4 else w_ref_val
+    q = _int4_codes(w_ref_val, hidden) if bits == 4 else w_ref_val
     return dequantize(q, scale, axis=0).astype(act_dtype)
 
 

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import compat
+from repro.kernels import resolve_interpret
 
 
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, hout_ref,
@@ -72,7 +72,7 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, y_ref, hout_ref,
                                              "interpret"))
 def ssd_chunk_scan(x: jax.Array, dt: jax.Array, a: jax.Array, bm: jax.Array,
                    cm: jax.Array, d_skip: jax.Array, *, q_chunk: int = 256,
-                   block_h: int = 8, interpret: bool = True):
+                   block_h: int = 8, interpret: bool | None = None):
     """Fused SSD scan (n_groups=1).
 
     x: [B, L, H, P]; dt: [B, L, H] (post-softplus); a: [H] (negative);
@@ -110,7 +110,8 @@ def ssd_chunk_scan(x: jax.Array, dt: jax.Array, a: jax.Array, bm: jax.Array,
             jax.ShapeDtypeStruct((B, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bh, P, N), jnp.float32)],
-        compiler_params=compat.compiler_params("parallel", "parallel", "arbitrary"),
-        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=resolve_interpret(interpret),
     )(x, dt, a2, bm, cm, d2)
     return y, h_final

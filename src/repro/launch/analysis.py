@@ -1,9 +1,10 @@
 """Roofline analysis from compiled artifacts (no hardware required).
 
-Terms (per device, seconds) — v5e constants:
-  compute    = HLO_FLOPs / 197e12          (bf16 MXU peak)
-  memory     = HLO_bytes / 819e9           (HBM bandwidth)
-  collective = collective_bytes / 50e9     (ICI per-link)
+Terms (per device, seconds), with the peaks of the device the program was
+compiled for (:data:`PEAKS`, keyed by ``jax.Device.device_kind``):
+  compute    = HLO_FLOPs / peak bf16 FLOP/s
+  memory     = HLO_bytes / HBM bandwidth
+  collective = collective_bytes / ICI bandwidth per link
 
 ``cost_analysis()`` on the SPMD-partitioned module reports *per-device*
 flops/bytes.  collective_bytes is parsed from the partitioned HLO text:
@@ -18,9 +19,36 @@ import re
 
 import numpy as np
 
-PEAK_FLOPS = 197e12          # bf16 per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    """Per-chip roofline peaks."""
+
+    flops: float        # bf16 FLOP/s
+    hbm_bw: float       # HBM bytes/s
+    ici_bw: float       # ICI bytes/s per link
+
+
+#: Device kind (as ``jax.devices()[0].device_kind`` reports it) → peaks.
+#: TPU v5e — Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
+#: 16 GB HBM at 819 GB/s, 1,600 Gbit/s inter-chip interconnect per chip
+#: (four links, 50 GB/s each).
+PEAKS = {
+    "TPU v5 lite": Peaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+#: The device the analytic models (``repro.dse.tpu_model``) and the
+#: production-mesh dry runs price: the chip this repo serves on.
+MODELED_DEVICE = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> Peaks:
+    """The peaks of ``device_kind``; a device not in :data:`PEAKS` raises."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no roofline peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 _COLL_RE = re.compile(
     r"=\s*([a-z0-9_]+)\[([0-9,]*)\]"                  # dtype[shape]
@@ -89,16 +117,19 @@ class Roofline:
         return self.t_compute / max(self.t_bound, 1e-30)
 
 
-def analyse(compiled, hlo_text: str | None = None) -> Roofline:
+def analyse(compiled, device_kind: str,
+            hlo_text: str | None = None) -> Roofline:
+    """Roofline terms of ``compiled`` on a ``device_kind`` chip."""
+    pk = peaks(device_kind)
     ca = compiled.cost_analysis() or {}
     flops = float(ca.get("flops", 0.0))
     bytes_hbm = float(ca.get("bytes accessed", 0.0))
     text = hlo_text if hlo_text is not None else compiled.as_text()
     coll = collective_bytes(text)
     weighted = sum(_FACTORS[k] * v for k, v in coll.items())
-    t_c = flops / PEAK_FLOPS
-    t_m = bytes_hbm / HBM_BW
-    t_x = weighted / ICI_BW
+    t_c = flops / pk.flops
+    t_m = bytes_hbm / pk.hbm_bw
+    t_x = weighted / pk.ici_bw
     bott = max((t_c, "compute"), (t_m, "memory"), (t_x, "collective"))[1]
     ma = compiled.memory_analysis()
     mem = {}

@@ -19,7 +19,6 @@ import traceback
 import jax
 
 from repro.configs import ALIASES, get_config
-from repro.kernels import compat
 from repro.launch import analysis, mesh as mesh_lib, specs
 from repro.models.config import SHAPES, shape_applicable
 
@@ -62,7 +61,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, *,
             job = specs.train_job(cfg, shape, mesh, microbatches=microbatches)
         if SHAPES[shape].kind == "decode" and "kv8" in opts:
             job = specs.decode_job(cfg, shape, mesh, kv_quant=True)
-        with opt_stack, compat.set_mesh(mesh):
+        with opt_stack, jax.set_mesh(mesh):
             lowered = jax.jit(job.fn, in_shardings=job.in_shardings,
                               out_shardings=job.out_shardings).lower(*job.args)
             t_lower = time.time() - t0
@@ -70,7 +69,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, *,
             t_compile = time.time() - t0 - t_lower
             print(compiled.memory_analysis())
             hlo = compiled.as_text()
-            roof = analysis.analyse(compiled, hlo)
+            roof = analysis.analyse(compiled, analysis.MODELED_DEVICE, hlo)
             ca = compiled.cost_analysis() or {}
             print({k: ca[k] for k in ("flops", "bytes accessed") if k in ca})
         mf = analysis.model_flops(cfg, SHAPES[shape], chips)
@@ -132,11 +131,11 @@ def run_probes(cfg, shape: str, mesh, opts: tuple = ()) -> dict:
     with stack, L.attention_override(**specs._attn_blocks_for(cell.seq_len)):
         for pr in specs.probe_jobs(cfg, shape, mesh,
                                    kv_quant="kv8" in opts):
-            with compat.set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 compiled = jax.jit(
                     pr.fn, in_shardings=pr.in_shardings).lower(
                         *pr.args).compile()
-                roof = analysis.analyse(compiled)
+                roof = analysis.analyse(compiled, analysis.MODELED_DEVICE)
             tot["flops"] += roof.flops * pr.multiplier
             tot["bytes"] += roof.bytes_hbm * pr.multiplier
             tot["coll"] += roof.bytes_collective * pr.multiplier
@@ -145,9 +144,10 @@ def run_probes(cfg, shape: str, mesh, opts: tuple = ()) -> dict:
                 "flops": roof.flops, "bytes": roof.bytes_hbm,
                 "collective_bytes": roof.bytes_collective,
                 "collectives": roof.coll_by_kind})
-    t_c = tot["flops"] / analysis.PEAK_FLOPS
-    t_m = tot["bytes"] / analysis.HBM_BW
-    t_x = tot["coll"] / analysis.ICI_BW
+    pk = analysis.peaks(analysis.MODELED_DEVICE)
+    t_c = tot["flops"] / pk.flops
+    t_m = tot["bytes"] / pk.hbm_bw
+    t_x = tot["coll"] / pk.ici_bw
     bott = max((t_c, "compute"), (t_m, "memory"), (t_x, "collective"))[1]
     return {
         "flops_per_device": tot["flops"],

@@ -16,7 +16,6 @@ import json
 import jax
 
 from repro.configs import ALIASES, get_config
-from repro.kernels import compat
 from repro.launch import analysis, mesh as mesh_lib, specs
 from repro.models import backbone, layers, moe
 from repro.models.config import SHAPES
@@ -68,18 +67,19 @@ def measure(arch: str, shape: str, variants: set[str], *,
                                        kv_quant="kv8" in variants):
                 if probe_filter and probe_filter not in pr.name:
                     continue
-                with compat.set_mesh(mesh):
+                with jax.set_mesh(mesh):
                     compiled = jax.jit(
                         pr.fn, in_shardings=pr.in_shardings).lower(
                             *pr.args).compile()
-                    roof = analysis.analyse(compiled)
+                    roof = analysis.analyse(compiled, analysis.MODELED_DEVICE)
                 tot["flops"] += roof.flops * pr.multiplier
                 tot["bytes"] += roof.bytes_hbm * pr.multiplier
                 tot["coll"] += roof.bytes_collective * pr.multiplier
                 details.append((pr.name, pr.multiplier, roof))
-    t_c = tot["flops"] / analysis.PEAK_FLOPS
-    t_m = tot["bytes"] / analysis.HBM_BW
-    t_x = tot["coll"] / analysis.ICI_BW
+    pk = analysis.peaks(analysis.MODELED_DEVICE)
+    t_c = tot["flops"] / pk.flops
+    t_m = tot["bytes"] / pk.hbm_bw
+    t_x = tot["coll"] / pk.ici_bw
     return {"t_compute": t_c, "t_memory": t_m, "t_collective": t_x,
             "t_bound": max(t_c, t_m, t_x),
             "bottleneck": max((t_c, "compute"), (t_m, "memory"),

@@ -8,18 +8,23 @@ slow-ICI/DCN dimension; only data parallelism (gradient reduce) crosses it.
 
 from __future__ import annotations
 
-from repro.kernels import compat
+import jax
+
+
+def _auto_mesh(shape, axes):
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """1-device mesh with the same axis names (CPU tests / smoke runs)."""
-    return compat.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
 
 
 def make_data_mesh(n_data: int, *, model: int = 1):
@@ -31,7 +36,6 @@ def make_data_mesh(n_data: int, *, model: int = 1):
     submeshes of any size that fits, so one process can sweep 1/2/4/8-way
     sharding without restarting.
     """
-    import jax
     import numpy as np
 
     need = n_data * model

@@ -53,7 +53,6 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import mcd, rnn
-from repro.kernels.compat import shard_map
 from repro.launch import mesh as mesh_lib
 
 #: H above which ``"auto"`` stops replicating the sequence kernel's weights.
@@ -366,11 +365,11 @@ def _data_sharded_fn(mesh, dp, backend, cell, p, layer_offset, interpret,
                   for px, ph in presence)        # masks are [B, G, dim] too
     cspec = carry_specs(n_layers, mesh, po, cell=cell)
     out_spec = (bs["x_seq"] if return_sequence else None, cspec)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), bs["x_seq"], mspec, bs["rows"], P(), bs["lengths"],
                   cspec if has_state else None),
-        out_specs=out_spec, check_rep=False)
+        out_specs=out_spec, check_vma=False)
     return jax.jit(sharded)
 
 

@@ -74,6 +74,7 @@ import numpy as np
 from repro.ckpt import checkpoint
 from repro.core import autoencoder as ae, classifier as clf, mcd
 from repro.data import ecg
+from repro.launch import compile_cache
 from repro.serve import (FleetEngine, JsonlSink, StreamingEngine, TenantSpec,
                          pow2_ladder, prewarm, summarize)
 
@@ -243,7 +244,8 @@ def run_fleet(args):
         print(f"tick metrics -> {args.metrics_out}")
 
 
-def main():
+def parse_args(argv=None) -> argparse.Namespace:
+    """The launcher's flags (``argv`` None: the command line)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--tenants", default=None, metavar="FLEET_JSON",
                     help="multi-tenant fleet mode: serve the tenant table "
@@ -329,16 +331,26 @@ def main():
                     help="restore the latest snapshot in --snapshot-dir "
                     "and continue every stream where it left off")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
-    total = args.overload or args.sessions
+    args = ap.parse_args(argv)
     if args.resume and not args.snapshot_dir:
         ap.error("--resume requires --snapshot-dir")
     if args.early_exit_threshold is not None and args.shards:
         ap.error("--early-exit-threshold is incompatible with --shards "
                  "(sharded launches need uniform chains per session)")
-    if args.tenants:
-        return run_fleet(args)
+    return args
 
+
+def _quiet(_msg: str) -> None:
+    pass
+
+
+def build_engine(args, *, log=print) -> StreamingEngine:
+    """The single-model engine ``args`` describe, prewarmed if asked.
+
+    ``--shards N`` places it on a data mesh over the first N devices.
+    ``log`` None builds silently.
+    """
+    say = log or _quiet
     cfg = clf.ClassifierConfig(
         hidden=args.hidden, num_layers=args.layers, cell=args.cell,
         mcd=mcd.MCDConfig(p=args.p, placement=args.placement,
@@ -350,7 +362,7 @@ def main():
     if args.shards:
         from repro.launch.mesh import make_data_mesh
         mesh = make_data_mesh(args.shards)
-        print(f"sharding launches over {args.shards} devices (data axis)")
+        say(f"sharding launches over {args.shards} devices (data axis)")
     sink = JsonlSink(args.metrics_out) if args.metrics_out else None
     # The ladder is the operator's launch-shape budget: this launcher never
     # submits chunks longer than --chunk-len, so cap the rungs there (the
@@ -367,8 +379,143 @@ def main():
     if args.prewarm:
         t0 = time.perf_counter()
         caps = prewarm(eng)
-        print(f"prewarmed capacities {caps} in "
-              f"{time.perf_counter() - t0:.2f}s")
+        say(f"prewarmed capacities {caps} in "
+            f"{time.perf_counter() - t0:.2f}s")
+    return eng
+
+
+def open_streams(eng, args, *, log=print):
+    """Admit every stream, or with ``--resume`` restore the snapshot.
+
+    Returns ``(streams, labels, done)``: the per-session signals, their
+    beat labels, and the sids a resumed run had already finished.
+    ``log`` None opens silently.
+    """
+    say = log or _quiet
+    total = args.overload or args.sessions
+    # Streams are regenerated deterministically from their generation
+    # params; the per-stream cursor lives *in* the session (steps served
+    # so far), so a resumed process only needs the snapshot + those params
+    # to pick up.  The params ride the snapshot — a resume with different
+    # flags would otherwise silently serve different signal content.
+    done: set[str] = set()
+    if args.resume:
+        extra = eng.restore(args.snapshot_dir)
+        done = set(extra.get("done", []))
+        gen = extra.get("gen")
+        if gen and (gen["total"], gen["beats"]) != (total, args.beats):
+            say(f"resume: adopting snapshot stream params "
+                f"total={gen['total']} beats={gen['beats']} "
+                f"(CLI values differ)")
+        if gen:
+            total, args.beats = int(gen["total"]), int(gen["beats"])
+        say(f"resumed tick {eng.tick}: live={eng.active_sessions} "
+            f"queued={eng.queued_sessions} done={sorted(done)}")
+        # (--seed / --samples mismatches are already rejected by
+        # eng.restore: they would change the Bayesian draw itself.)
+    streams, labels = build_streams(total, args.beats, args.seed)
+    if not args.resume:
+        # Admit everything up front: the first --sessions go live, the
+        # rest wait in the queue (earlier streams get higher priority —
+        # think triage order) and go live as streams finish.
+        for k in range(total):
+            live = eng.admit(f"ecg-{k}", priority=total - k)
+            tag = "live" if live is not None else "queued"
+            say(f"admit ecg-{k}: {tag}")
+    return streams, labels, done
+
+
+def serve(eng, streams, labels, args, *, done=None, ctrl=None,
+          until_tick: int | None = None, log=print):
+    """Serve the admitted streams chunk by chunk until every one is closed.
+
+    Each tick submits the next ``--chunk-len`` steps (jittered with
+    ``--ragged``) of every live stream, closes the finished ones (which
+    admits queued streams into the freed rows) and snapshots every
+    ``--snapshot-every`` ticks when ``--snapshot-dir`` is set.  ``ctrl``
+    is an optional :class:`~repro.serve.CoDesignController`, which may
+    swap in a reconfigured engine.  ``until_tick`` stops early, once the
+    engine's tick counter reaches it.  ``log`` None serves silently (and
+    skips the per-tick host reads the progress lines need).
+
+    Returns ``(engine, done, served)``: the engine serving at the end (the
+    controller's replacement, if any), the finished sids, and every
+    :class:`~repro.serve.stream.ChunkResult` by sid in serving order.
+    """
+    say = log or _quiet
+    total = len(streams)
+    done = set() if done is None else done
+    served: dict[str, list] = {}
+    rng = np.random.default_rng(args.seed + 1)
+    while len(done) < total and (until_tick is None
+                                 or eng.tick < until_tick):
+        chunks = {}
+        for sid in eng.active_sessions:
+            k = int(sid.split("-")[1])
+            pos = eng.store.get(sid).steps
+            if pos >= len(streams[k]):
+                continue
+            n = args.chunk_len
+            if args.ragged:
+                n = int(rng.integers(1, args.chunk_len + 1))
+            chunks[sid] = jnp.asarray(streams[k][pos:pos + n], jnp.float32)
+        results = eng.step(chunks)
+        for sid, res in results.items():
+            served.setdefault(sid, []).append(res)
+        if log is not None:
+            log(_tick_line(eng, results, args))
+        if ctrl is not None:
+            rec = ctrl.maybe_reconfigure()
+            if rec is not None:
+                say(f"  controller[{rec.reason}] applied={rec.applied} "
+                    f"winner={rec.winner} "
+                    f"p95={rec.observed['duration_s_p95'] * 1e3:.2f}ms")
+            eng = ctrl.engine       # maybe a prewarmed replacement
+
+        for sid in list(eng.active_sessions):
+            k = int(sid.split("-")[1])
+            if eng.store.get(sid).steps >= len(streams[k]):
+                sess = eng.close_session(sid)      # frees a row; queue drains
+                done.add(sid)
+                say(f"{sid}: served {sess.steps} steps in {sess.chunks} "
+                    f"chunks (beat labels {labels[k]})")
+        if args.snapshot_dir and eng.tick % args.snapshot_every == 0:
+            path = eng.snapshot(args.snapshot_dir, extra={
+                "done": sorted(done),
+                "gen": {"total": total, "beats": args.beats,
+                        "seed": args.seed}})
+            checkpoint.keep_last(args.snapshot_dir, args.snapshot_keep)
+            say(f"  snapshot -> {path}")
+    return eng, done, served
+
+
+def _tick_line(eng, results, args) -> str:
+    """One tick's progress line: every served session's class and
+    uncertainty, plus the launch shape and queue."""
+    line = []
+    for sid, res in sorted(results.items()):
+        su = res.summary
+        cls = int(np.argmax(np.asarray(su.probs)))
+        line.append(f"{sid}@{res.steps_total:4d} cls={cls} "
+                    f"H={float(su.predictive_entropy):5.3f} "
+                    f"MI={float(su.mutual_information):6.4f}")
+    m = eng.last_metrics
+    stat = (f"cap={m.capacity} q={m.queue_depth} "
+            f"waste={m.pad_waste:4.2f}" if m else "idle")
+    if m and args.early_exit_threshold is not None:
+        stat += f" chains={m.active_chains}"
+        if m.reclaimed_rows:
+            stat += f" -{m.reclaimed_rows}"
+    return f"tick {eng.tick:3d} [{stat}] | " + " | ".join(line)
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    compile_cache.enable()
+    if args.tenants:
+        return run_fleet(args)
+
+    eng = build_engine(args)
     ctrl = None
     if args.controller:
         from repro.serve import CoDesignController, SLOPolicy
@@ -382,94 +529,16 @@ def main():
               f"tokens/s>={args.min_tokens_per_sec} "
               f"S>={args.min_samples} | knobs S{list(ctrl.knobs.samples)}")
 
-    # Streams are regenerated deterministically from their generation
-    # params; the per-stream cursor lives *in* the session (steps served
-    # so far), so a resumed process only needs the snapshot + those params
-    # to pick up.  The params ride the snapshot — a resume with different
-    # flags would otherwise silently serve different signal content.
-    done: set[str] = set()
-    if args.resume:
-        extra = eng.restore(args.snapshot_dir)
-        done = set(extra.get("done", []))
-        gen = extra.get("gen")
-        if gen and (gen["total"], gen["beats"]) != (total, args.beats):
-            print(f"resume: adopting snapshot stream params "
-                  f"total={gen['total']} beats={gen['beats']} "
-                  f"(CLI values differ)")
-        if gen:
-            total, args.beats = int(gen["total"]), int(gen["beats"])
-        print(f"resumed tick {eng.tick}: live={eng.active_sessions} "
-              f"queued={eng.queued_sessions} done={sorted(done)}")
-        # (--seed / --samples mismatches are already rejected by
-        # eng.restore: they would change the Bayesian draw itself.)
-    streams, labels = build_streams(total, args.beats, args.seed)
-    if not args.resume:
-        # Admit everything up front: the first --sessions go live, the
-        # rest wait in the queue (earlier streams get higher priority —
-        # think triage order) and go live as streams finish.
-        for k in range(total):
-            live = eng.admit(f"ecg-{k}", priority=total - k)
-            tag = "live" if live is not None else "queued"
-            print(f"admit ecg-{k}: {tag}")
-
-    print(f"streaming {total} sessions ({args.sessions} live rows) × "
+    streams, labels, done = open_streams(eng, args)
+    cfg = eng.cfg
+    print(f"streaming {len(streams)} sessions ({args.sessions} live rows) × "
           f"{args.beats} beats (T={ecg.T_STEPS} each) | S={args.samples} "
           f"chains/session p={cfg.mcd.p} "
           f"B={mcd.placement_str(cfg.mcd.placement)} "
           f"cell={args.cell} backend={args.backend} "
           f"precision={args.precision or 'native'} "
           f"capacity={args.capacity}")
-
-    rng = np.random.default_rng(args.seed + 1)
-    while len(done) < total:
-        chunks = {}
-        for sid in eng.active_sessions:
-            k = int(sid.split("-")[1])
-            pos = eng.store.get(sid).steps
-            if pos >= len(streams[k]):
-                continue
-            n = args.chunk_len
-            if args.ragged:
-                n = int(rng.integers(1, args.chunk_len + 1))
-            chunks[sid] = jnp.asarray(streams[k][pos:pos + n], jnp.float32)
-        results = eng.step(chunks)
-        line = []
-        for sid, res in sorted(results.items()):
-            su = res.summary
-            cls = int(np.argmax(np.asarray(su.probs)))
-            line.append(f"{sid}@{res.steps_total:4d} cls={cls} "
-                        f"H={float(su.predictive_entropy):5.3f} "
-                        f"MI={float(su.mutual_information):6.4f}")
-        m = eng.last_metrics
-        stat = (f"cap={m.capacity} q={m.queue_depth} "
-                f"waste={m.pad_waste:4.2f}" if m else "idle")
-        if m and args.early_exit_threshold is not None:
-            stat += f" chains={m.active_chains}"
-            if m.reclaimed_rows:
-                stat += f" -{m.reclaimed_rows}"
-        print(f"tick {eng.tick:3d} [{stat}] | " + " | ".join(line))
-        if ctrl is not None:
-            rec = ctrl.maybe_reconfigure()
-            if rec is not None:
-                print(f"  controller[{rec.reason}] applied={rec.applied} "
-                      f"winner={rec.winner} "
-                      f"p95={rec.observed['duration_s_p95'] * 1e3:.2f}ms")
-            eng = ctrl.engine       # maybe a prewarmed replacement
-
-        for sid in list(eng.active_sessions):
-            k = int(sid.split("-")[1])
-            if eng.store.get(sid).steps >= len(streams[k]):
-                sess = eng.close_session(sid)      # frees a row; queue drains
-                done.add(sid)
-                print(f"{sid}: served {sess.steps} steps in {sess.chunks} "
-                      f"chunks (beat labels {labels[k]})")
-        if args.snapshot_dir and eng.tick % args.snapshot_every == 0:
-            path = eng.snapshot(args.snapshot_dir, extra={
-                "done": sorted(done),
-                "gen": {"total": total, "beats": args.beats,
-                        "seed": args.seed}})
-            checkpoint.keep_last(args.snapshot_dir, args.snapshot_keep)
-            print(f"  snapshot -> {path}")
+    eng, done, _ = serve(eng, streams, labels, args, done=done, ctrl=ctrl)
 
     if eng.metrics:
         agg = summarize(eng.metrics)

@@ -619,7 +619,7 @@ class CoDesignController:
             precision=new.precision,
             early_exit_threshold=(None if mesh is not None
                                   else old.early_exit_threshold),
-            min_samples=floor, interpret=old.interpret)
+            min_samples=floor)
         if (old._scheduler is not None and eng._scheduler is not None
                 and eng._scheduler.ladder == old._scheduler.ladder):
             # Same ladder → carry the chunk-length observation window, so

@@ -138,7 +138,7 @@ class FleetEngine:
       metrics_sink: where tenant-tagged per-tick :class:`TickMetrics` go
         (fleet-level; each group engine keeps a small private ring for its
         own launch-shape bookkeeping).
-      mesh, policy, interpret: forwarded to every group engine.
+      mesh, policy: forwarded to every group engine.
 
     Session ids are namespaced ``"tenant/sid"`` inside the launch groups so
     tenants sharing a group can never collide; the public API (``admit``,
@@ -150,14 +150,14 @@ class FleetEngine:
                  admit_per_tick: int | None = None,
                  metrics_window: int = 4096,
                  metrics_sink: MetricsSink | None = None,
-                 mesh=None, policy=None, interpret: bool | None = None):
+                 mesh=None, policy=None):
         if not tenants:
             raise ValueError("a fleet needs at least one tenant")
         names = [t.name for t in tenants]
         if len(set(names)) != len(names):
             raise ValueError(f"duplicate tenant names in {names}")
         self.specs: dict[str, TenantSpec] = {t.name: t for t in tenants}
-        self._mesh, self._policy, self._interpret = mesh, policy, interpret
+        self._mesh, self._policy = mesh, policy
         # Launch-group folding: tenants sharing the same weights *object*
         # and the same compiled signature (config incl. cell/H/NL/mcd,
         # backend, precision, chunk policy, early-exit policy) share one
@@ -236,8 +236,7 @@ class FleetEngine:
                 early_exit_threshold=lead.early_exit_threshold,
                 min_samples=min(lead.min_samples, ceiling),
                 student=lead.student,
-                student_escalate_threshold=lead.student_escalate_threshold,
-                interpret=self._interpret)
+                student_escalate_threshold=lead.student_escalate_threshold)
         group = _Group(name=gname, engine=engine, tenants=list(members))
         self.groups[gname] = group
         for m in members:
@@ -547,8 +546,7 @@ class FleetEngine:
             mesh=self._mesh, policy=self._policy,
             precision=new_spec.precision,
             early_exit_threshold=new_spec.early_exit_threshold,
-            min_samples=min(new_spec.min_samples, new_ceiling),
-            interpret=self._interpret)
+            min_samples=min(new_spec.min_samples, new_ceiling))
         cursor = old_engine.store.next_row
         part_dtypes = carry_dtypes(engine.cell, new_spec.precision,
                                    engine.backend)

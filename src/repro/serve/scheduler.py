@@ -185,8 +185,6 @@ def prewarm(engine, *, dtype=None) -> list[int]:
     Returns the list of capacities compiled, ascending.
     """
     import jax
-    import jax.numpy as jnp
-    import numpy as np
 
     if engine._scheduler is not None:
         caps = list(engine._scheduler.ladder)
@@ -196,18 +194,30 @@ def prewarm(engine, *, dtype=None) -> list[int]:
         raise ValueError(
             "prewarm needs a bounded shape family: chunk_capacity must be "
             "an int or 'auto' (dynamic mode compiles per observed shape)")
-    dtype = np.dtype(np.float32 if dtype is None else dtype)
-    s = engine.n_samples
-    nb = engine._slot_count(0) * s      # the fixed-mode tick batch layout
-    in_dim = engine.cfg.input_dim
     for cap in caps:
-        x = jnp.zeros((nb, cap, in_dim), dtype)
-        rows = jnp.zeros((nb,), jnp.uint32)
-        lengths = jnp.ones((nb,), jnp.int32)
-        state = engine._gather_states([], dtype, n_pad=nb)
-        outs, states = engine._apply(x, rows, lengths, state)
-        jax.block_until_ready((outs, states))
+        jax.block_until_ready(engine._apply(*launch_args(engine, cap, dtype)))
     return caps
+
+
+def launch_args(engine, capacity: int, dtype=None) -> tuple:
+    """Operands of a fixed-shape tick launch at ``capacity`` timesteps.
+
+    ``(x, rows, lengths, state)`` for ``engine._apply`` in the exact
+    layout :meth:`~repro.serve.stream.StreamingEngine.step` launches —
+    ``max_sessions`` slots padded to the shard multiple, S chains each,
+    the materialized zero-state pytree — so compiling them compiles the
+    serving graph.  ``dtype`` is the chunk dtype (default float32).
+    """
+    import jax.numpy as jnp
+    import numpy as np
+
+    dtype = np.dtype(np.float32 if dtype is None else dtype)
+    nb = engine._slot_count(0) * engine.n_samples
+    x = jnp.zeros((nb, capacity, engine.cfg.input_dim), dtype)
+    rows = jnp.zeros((nb,), jnp.uint32)
+    lengths = jnp.ones((nb,), jnp.int32)
+    state = engine._gather_states([], dtype, n_pad=nb)
+    return x, rows, lengths, state
 
 
 def percentile(values: Sequence[float], p: float) -> float:

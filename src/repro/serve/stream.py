@@ -244,7 +244,9 @@ class StreamingEngine:
         is bit-identical to an always-MC session attached at that carry.
         None: students never escalate (still serviceable via an explicit
         ``store.grow``).
-      interpret: forwarded to the Pallas backends (default: auto off-TPU).
+
+    The Pallas backends lower natively on a TPU backend and run in the
+    Pallas interpreter elsewhere (:func:`repro.kernels.resolve_interpret`).
     """
 
     def __init__(self, params, cfg, *, backend: str = "pallas_seq",
@@ -258,8 +260,7 @@ class StreamingEngine:
                  early_exit_threshold: float | None = None,
                  min_samples: int = 1,
                  student=None,
-                 student_escalate_threshold: float | None = None,
-                 interpret: bool | None = None):
+                 student_escalate_threshold: float | None = None):
         if isinstance(cfg, _clf.ClassifierConfig):
             self.kind = "classifier"
         elif isinstance(cfg, _ae.AutoencoderConfig):
@@ -272,7 +273,6 @@ class StreamingEngine:
         if precision is not None:
             _quant.check_precision(precision)
         self.precision = precision
-        self.interpret = interpret
         self.chunk_capacity = chunk_capacity
         self.max_sessions = max_sessions
         self.mesh = mesh
@@ -738,6 +738,14 @@ class StreamingEngine:
         initial_state = self._gather_states(sessions, dtype, n_pad)
 
         outs, states = self._apply(x_batch, rows, lengths, initial_state)
+        if self._shards > 1:
+            # The summaries below run op by op; on a sharded array XLA
+            # partitions each op and may round a reduction differently from
+            # one device (ulp-level on a TPU).  The outputs are small, so
+            # gather them onto one device: sharded == unsharded, bit for bit.
+            dev = self.mesh.devices.flat[0]
+            outs = tuple(None if o is None else jax.device_put(o, dev)
+                         for o in outs)
         if self.kind == "classifier":
             (logits,) = outs
         else:

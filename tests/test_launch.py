@@ -10,8 +10,7 @@ import numpy as np
 import pytest
 
 from repro.configs import ARCH_IDS, get_config
-from repro.kernels import compat
-from repro.launch import analysis, mesh as mesh_lib, specs
+from repro.launch import analysis, compile_cache, mesh as mesh_lib, specs
 from repro.models import backbone
 from repro.models.config import SHAPES
 
@@ -40,6 +39,45 @@ class TestCollectiveParser:
                               memory_per_device={})
         # factor table: all-reduce weighted 2×
         assert analysis._FACTORS["all-reduce"] == 2.0
+
+
+class TestPeaks:
+    def test_v5e_peaks_keyed_by_device_kind(self):
+        pk = analysis.peaks("TPU v5 lite")
+        assert (pk.flops, pk.hbm_bw, pk.ici_bw) == (197e12, 819e9, 50e9)
+        assert analysis.peaks(analysis.MODELED_DEVICE) is pk
+
+    @pytest.mark.parametrize("kind", ["cpu", "TPU v6 lite", ""])
+    def test_unknown_device_raises(self, kind):
+        with pytest.raises(ValueError, match="no roofline peaks"):
+            analysis.peaks(kind)
+
+
+class TestCompileCache:
+    @pytest.fixture(autouse=True)
+    def _restore_config(self):
+        saved = (jax.config.jax_compilation_cache_dir,
+                 jax.config.jax_persistent_cache_min_compile_time_secs)
+        yield
+        jax.config.update("jax_compilation_cache_dir", saved[0])
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          saved[1])
+
+    def test_env_dir_used_as_is(self, monkeypatch, tmp_path):
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        before = jax.config.jax_compilation_cache_dir
+        assert compile_cache.enable() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+
+    def test_default_is_fixed_ignored_checkout_path(self, monkeypatch):
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        path = compile_cache.enable()
+        assert path == str(compile_cache.CHECKOUT_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == path
+        root = compile_cache.CHECKOUT_CACHE_DIR.parent
+        assert (root / "pyproject.toml").exists()
+        assert ".jax_cache/" in (root / ".gitignore").read_text().split()
 
 
 class TestActiveParams:
@@ -101,7 +139,7 @@ class TestJobsOnHostMesh:
         mc.SHAPES["tiny"] = tiny
         try:
             job = train_job(cfg, "tiny", mesh)
-            with compat.set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 compiled = jax.jit(job.fn, in_shardings=job.in_shardings,
                                    out_shardings=job.out_shardings
                                    ).lower(*job.args).compile()
@@ -112,7 +150,7 @@ class TestJobsOnHostMesh:
             opt = optimizer.init(params)
             batch = {"tokens": jnp.zeros((4, 16), jnp.int32),
                      "targets": jnp.zeros((4, 16), jnp.int32)}
-            with compat.set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 p2, o2, metrics = compiled(params, opt, batch,
                                            jnp.zeros((), jnp.int32))
             assert np.isfinite(float(metrics["loss"]))

@@ -21,7 +21,7 @@ def _weights(key, i, g, h, scale=1.0):
 
 
 class TestRoundTrip:
-    @settings(max_examples=15)
+    @settings(max_examples=15, deadline=None)
     @given(bits=st.sampled_from([8, 4]),
            i=st.integers(1, 24), h=st.integers(1, 24),
            key=st.integers(0, 2**16), amp=st.floats(1e-3, 100.0))
@@ -34,7 +34,7 @@ class TestRoundTrip:
         bound = np.asarray(s, np.float64)[None] / 2 + 1e-6 * amp
         assert (np.abs(w - deq) <= bound).all()
 
-    @settings(max_examples=10)
+    @settings(max_examples=10, deadline=None)
     @given(bits=st.sampled_from([8, 4]), key=st.integers(0, 2**16))
     def test_codes_within_symmetric_range(self, bits, key):
         q, _ = quantize.quantize(_weights(key, 8, 4, 8), bits, axis=0)
@@ -43,7 +43,7 @@ class TestRoundTrip:
         assert qn.dtype == np.int8
         assert qn.min() >= -qmax and qn.max() <= qmax
 
-    @settings(max_examples=10)
+    @settings(max_examples=10, deadline=None)
     @given(bits=st.sampled_from([8, 4]), key=st.integers(0, 2**16),
            h=st.integers(1, 16))
     def test_layout_invariance(self, bits, key, h):
@@ -85,7 +85,7 @@ class TestDegenerateChannels:
 
 
 class TestInt4Packing:
-    @settings(max_examples=15)
+    @settings(max_examples=15, deadline=None)
     @given(h=st.integers(1, 33), key=st.integers(0, 2**16))
     def test_pack_unpack_roundtrip_any_width(self, h, key):
         """Exact for every H, odd widths included (pad nibble dropped)."""

@@ -8,7 +8,6 @@ import numpy as np
 import pytest
 
 from repro.ckpt import checkpoint
-from repro.kernels import compat
 from repro.train import optimizer, trainer
 
 
@@ -126,7 +125,8 @@ class TestCheckpoint:
         d = str(tmp_path / "ck")
         tree = {"w": jnp.arange(16.0).reshape(4, 4)}
         checkpoint.save(d, 1, tree)
-        mesh = compat.make_mesh((1,), ("data",))
+        mesh = jax.make_mesh((1,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         shardings = {"w": NamedSharding(mesh, P("data", None))}
         restored = checkpoint.restore(d, 1, tree, shardings=shardings)
         np.testing.assert_array_equal(np.asarray(restored["w"]),

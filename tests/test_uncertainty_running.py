@@ -110,7 +110,7 @@ def _check_regression(means, log_vars, sizes):
     _assert_reg_close(merged.finalize(), want)
 
 
-@settings(max_examples=60)
+@settings(max_examples=60, deadline=None)
 @given(seed=st.integers(0, 2**32 - 1))
 def test_classification_matches_batch_any_partition(seed):
     rng = np.random.default_rng(seed)
@@ -118,7 +118,7 @@ def test_classification_matches_batch_any_partition(seed):
     _check_classification(logits, _partitions(rng, logits.shape[0]))
 
 
-@settings(max_examples=60)
+@settings(max_examples=60, deadline=None)
 @given(seed=st.integers(0, 2**32 - 1))
 def test_regression_matches_batch_any_partition(seed):
     rng = np.random.default_rng(seed)
