@@ -1,0 +1,34 @@
+"""The program's MC-dropout classifier as a cell builds and counts it.
+
+A model family is one file here, found by the ``model`` of a configuration
+file: ``build`` makes the program's config and names its ``init``,
+``layer_widths`` gives the recurrent kernel's launches of one tick and
+``model_flops`` the matmul work of the chain-steps served.
+"""
+
+from bench import roofline
+
+
+def build(cfg: dict, mc):
+    """``(init, program config)`` for ``repro.core.classifier``."""
+    from repro.core import classifier
+
+    return classifier.init, classifier.ClassifierConfig(
+        input_dim=cfg["input_dim"], hidden=cfg["hidden"],
+        num_layers=cfg["num_layers"], num_classes=cfg["num_classes"],
+        cell=cfg["cell"], mcd=mc)
+
+
+def layer_widths(cfg: dict) -> list[tuple[int, int]]:
+    """``(I, H)`` of every kernel launch of one tick, in launch order."""
+    dims = [cfg["input_dim"]] + [cfg["hidden"]] * cfg["num_layers"]
+    return list(zip(dims[:-1], dims[1:]))
+
+
+def model_flops(cfg: dict, chain_steps: int, chunks_chains: int) -> int:
+    """Matmul FLOPs for ``chain_steps`` served chain-steps; the dense head
+    runs once per (chunk, chain) pair, ``chunks_chains`` of them."""
+    g = roofline.gates(cfg)
+    per_step = sum(2 * g * (i + h) * h for i, h in layer_widths(cfg))
+    return (chain_steps * per_step
+            + chunks_chains * 2 * cfg["hidden"] * cfg["num_classes"])
