@@ -287,11 +287,13 @@ def _run_stack_pallas(params, x_seq, masks, p, *, backend, return_sequence,
     for i, (layer_params, (zx, _)) in enumerate(zip(params, masks)):
         p_eff = p if zx is not None else 0.0
         state0 = initial_state[i] if initial_state is not None else None
-        inp, carry = stack_layer(*layer_params, inp, rows, seed,
-                                 layer_offset + i, p_eff, seq=seq,
-                                 initial_state=state0,
-                                 lengths=lengths, precision=precision,
-                                 interpret=interpret)
+        # The host side of each layer's dispatch, as a profiler span.
+        with jax.profiler.TraceAnnotation(f"rnn.layer{i}"):
+            inp, carry = stack_layer(*layer_params, inp, rows, seed,
+                                     layer_offset + i, p_eff, seq=seq,
+                                     initial_state=state0,
+                                     lengths=lengths, precision=precision,
+                                     interpret=interpret)
         states.append(carry)
     out = inp if return_sequence else None
     if return_all_states:

@@ -49,7 +49,11 @@ def pow2_ladder(max_capacity: int, *, first: int = 8) -> tuple[int, ...]:
 
 @dataclasses.dataclass
 class TickMetrics:
-    """Per-tick control-plane observables (host-side, no device sync)."""
+    """Per-tick control-plane observables (host-side, no device sync).
+
+    Times are host time: on an accelerator the device work a tick
+    dispatched may still be running when ``step`` returns.
+    """
 
     tick: int
     capacity: int          # launch T this tick (ladder rung / fixed / max len)
@@ -61,14 +65,22 @@ class TickMetrics:
     live_chain_steps: int  # live_steps x S MC chains (chain-timesteps)
     padded_steps: int      # batch_rows * capacity (chain-timesteps launched)
     pad_waste: float       # 1 - live_chain_steps/padded_steps
-    duration_s: float      # wall-clock of the engine tick (dispatch incl.)
-    tokens_per_sec: float  # live chain-timesteps / duration (proxy off-TPU)
+    duration_s: float      # host time of StreamingEngine.step (drain to
+                           # return), not device time: the launch it
+                           # dispatched may still run on an accelerator
+    tokens_per_sec: float  # live chain-timesteps / duration_s (host rate)
     shards: int = 1        # data-parallel width the tick launched across
     queue_wait_s: float = 0.0  # oldest-pending admission age at the drain
-    compiles: int = 0      # new stack-graph jit entries this tick (a slow
-                           # tick with compiles > 0 is a compile stall, not
-                           # overload — the co-design controller and any
-                           # operator reading the JSONL trail need the split)
+    compiles: int = 0      # backend compiles during the tick, eager ops
+                           # included (a slow tick with compiles > 0 is a
+                           # compile stall, not overload — the co-design
+                           # controller and any operator reading the JSONL
+                           # trail need the split)
+    phase_s: dict = dataclasses.field(default_factory=dict)
+                           # host time of each phase of the tick, keyed by
+                           # its span name (repro.serve.spans.PHASES); the
+                           # values sum to at most duration_s
+    gc_s: float = 0.0      # Python GC pauses that fell inside the tick
     dropped: int = 0       # admissions the store refused this tick (tickets
                            # drained out of the queue that could never go
                            # live — previously visible only in the engine's

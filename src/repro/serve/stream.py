@@ -40,7 +40,6 @@ from __future__ import annotations
 
 import dataclasses
 import json
-import time
 from collections import deque
 from typing import Any, Mapping, Protocol, Sequence, runtime_checkable
 
@@ -56,27 +55,10 @@ from repro.core.uncertainty import (ClassificationSummary, RegressionSummary,
                                     RunningRegressionSummary,
                                     classification_summary,
                                     regression_summary)
-from repro.serve import persistence as _persist
+from repro.serve import persistence as _persist, spans as _spans
 from repro.serve.admission import AdmissionQueue, DrainRejected
 from repro.serve.scheduler import AdaptiveTickScheduler, TickMetrics
 from repro.serve.sessions import Session, SessionStore
-
-
-def stack_compile_count() -> int:
-    """Total jit cache entries across the recurrent-stack entry points.
-
-    The delta across a tick is ``TickMetrics.compiles`` — how many *new*
-    stack graphs that tick had to build.  A latency spike with
-    ``compiles > 0`` is a compile stall (fix: ``scheduler.prewarm``); one
-    with ``compiles == 0`` is genuine overload (fix: shed load or let the
-    co-design controller downshift).  Counts the ``repro.kernels.ops``
-    jitted wrappers every unsharded backend dispatches through (the sharded
-    path caches whole-tick callables separately).
-    """
-    from repro.kernels import ops
-    fns = (ops.lstm_stack_layer, ops.fused_lstm_seq, ops.fused_lstm_layer,
-           ops.gru_stack_layer, ops.fused_gru_seq, ops.fused_gru_layer)
-    return sum(fn._cache_size() for fn in fns)
 
 
 @dataclasses.dataclass
@@ -665,207 +647,236 @@ class StreamingEngine:
         ``input_dim == 1``) signal slices; ``t`` may differ per session
         (ragged) and must be >= 1.  Every listed session must be open.
         Returns per-session :class:`ChunkResult`; carried state advances.
+
+        The call is one ``engine.step`` span and its phases are child spans
+        (:mod:`repro.serve.spans`); the tick's :class:`TickMetrics` carries
+        their host times in ``phase_s``, with the compiles and GC pauses
+        that fell inside it.
         """
-        self._drain()          # tick boundary: freed rows feed the wait-list
-        if not chunks:
-            return {}
-        # Head-of-line admission delay *after* the drain: how long the
-        # oldest stream that still couldn't get a row has been waiting.
-        queue_wait_s = self.queue.oldest_wait_s()
-        compiles_before = stack_compile_count()
-        t_start = time.perf_counter()
-        sessions, xs, lens = [], [], []
-        for sid, chunk in chunks.items():
-            sess = self.store.get(sid)
-            x = np.asarray(chunk)
-            if x.ndim == 1:
-                x = x[:, None]
-            if x.ndim != 2 or x.shape[0] < 1:
-                raise ValueError(f"chunk for {sid!r} must be [t>=1, "
-                                 f"input_dim], got shape {tuple(x.shape)}")
-            sessions.append(sess)
-            xs.append(x)
-            lens.append(x.shape[0])
-        # Per-session chain counts — S is session state, not an engine
-        # constant.  With every session at the ceiling (the threshold-off
-        # default) the layout below is byte-identical to the static-S
-        # engine's; sharded launches require exactly that (whole sessions
-        # per shard is only well-defined with one S).
-        s_list = [int(sess.rows.shape[0]) for sess in sessions]
-        if self._shards > 1 and any(si != self.n_samples for si in s_list):
-            raise ValueError(
-                "sharded launches need every session at the engine ceiling "
-                f"({self.n_samples} chains); got {s_list} — per-session S "
-                "would straddle shard boundaries")
-
-        if self._scheduler is not None:
-            t_max = self._scheduler.plan(lens)
-        elif self.chunk_capacity is not None:
-            if max(lens) > self.chunk_capacity:
-                raise ValueError(f"chunk of {max(lens)} steps exceeds "
-                                 f"chunk_capacity={self.chunk_capacity}")
-            t_max = self.chunk_capacity
-        else:
-            t_max = max(lens)
-        dtype = xs[0].dtype
-        slots = self._slot_count(len(sessions))
-        # Launch size: fixed-shape modes always budget ceiling chains per
-        # slot — retired chains become tail padding and the one-graph
-        # guarantee survives early exit.  Dynamic mode launches exactly the
-        # live chains, so retirement shrinks the actual compute.
-        live_chains = sum(s_list)
-        nb = slots * self.n_samples if (self._fixed or self._shards > 1) \
-            else live_chains
-        n_pad = nb - live_chains
-        # Batch assembly stages in host numpy — one device transfer per
-        # operand per tick, not O(sessions) tiny dispatches.  Session-major,
-        # chain-minor: session k's chains pack at offsets[k], matching the
-        # concatenated per-session mask rows (offset k*S when uniform).
-        x_host = np.zeros((nb, t_max, xs[0].shape[1]), dtype)
-        rows_host = np.zeros((nb,), np.uint32)
-        lens_host = np.ones((nb,), np.int32)
-        offsets, off = [], 0
-        for x, L, sess, si in zip(xs, lens, sessions, s_list):
-            sl = slice(off, off + si)
-            offsets.append(off)
-            x_host[sl, :L] = x[None]
-            rows_host[sl] = np.asarray(sess.rows)
-            lens_host[sl] = L
-            off += si
-        x_batch = jnp.asarray(x_host)
-        rows = jnp.asarray(rows_host)
-        lengths = jnp.asarray(lens_host)
-        initial_state = self._gather_states(sessions, dtype, n_pad)
-
-        outs, states = self._apply(x_batch, rows, lengths, initial_state)
-        if self._shards > 1:
-            # The summaries below run op by op; on a sharded array XLA
-            # partitions each op and may round a reduction differently from
-            # one device (ulp-level on a TPU).  The outputs are small, so
-            # gather them onto one device: sharded == unsharded, bit for bit.
-            dev = self.mesh.devices.flat[0]
-            outs = tuple(None if o is None else jax.device_put(o, dev)
-                         for o in outs)
-        if self.kind == "classifier":
-            (logits,) = outs
-        else:
-            mean, log_var, dec_out = outs
-
-        # Batched summaries over [s, group, ...] — per-session results are
-        # indexed out, not recomputed per session.  A uniform tick (the
-        # common case, and always when the threshold is off) is one reshape
-        # of the contiguous live prefix — the static engine's exact op
-        # sequence.  Ragged ticks group sessions by chain count (staged
-        # halving keeps distinct counts at most log2(S)+1) and gather each
-        # group's rows; values are launch-layout-invariant either way.
-        # Student sessions sit outside the chain-axis estimator entirely:
-        # their single deterministic row is decoded through the student
-        # heads below, and only the MC sessions group.
-        k_n = len(sessions)
-        summaries: list = [None] * k_n
-        stu_ks = [k for k in range(k_n) if sessions[k].mode == "student"]
-        mc_ks = [k for k in range(k_n) if sessions[k].mode != "student"]
-        mc_s = [s_list[k] for k in mc_ks]
-        groups = ([(s_list[0], list(range(k_n)))]
-                  if not stu_ks and len(set(s_list)) == 1
-                  else sorted({si: [k for k in mc_ks if s_list[k] == si]
-                               for si in set(mc_s)}.items()))
-        for si, ks in groups:
-            if len(ks) == k_n:
-                sel = lambda a: a.reshape((-1, si) + a.shape[1:])[:k_n]  # noqa: E731
-            else:
-                idx = jnp.asarray(np.concatenate(
-                    [np.arange(offsets[k], offsets[k] + si) for k in ks]))
-                sel = lambda a: a[idx].reshape((len(ks), si) + a.shape[1:])  # noqa: E731
-            if self.kind == "classifier":
-                per_chain = jnp.swapaxes(sel(logits), 0, 1)
-                batched = classification_summary(
-                    per_chain.astype(jnp.float32))
-                for j, k in enumerate(ks):
-                    summaries[k] = ClassificationSummary(
-                        *(v[j] for v in batched))
-            else:
-                mu = jnp.swapaxes(sel(mean), 0, 1)
-                lv = (None if log_var is None
-                      else jnp.swapaxes(sel(log_var), 0, 1))
-                batched = regression_summary(
-                    mu.astype(jnp.float32),
-                    None if lv is None else lv.astype(jnp.float32))
-                for j, k in enumerate(ks):
-                    summaries[k] = RegressionSummary(
-                        *(v[j] for v in batched))
-
-        # Distilled fast path: a student session's summary comes from the
-        # student heads on its one deterministic row's features — h_T for
-        # the classifier, the decoder hidden sequence for the autoencoder.
-        # One batched head call over every student row, indexed out like
-        # the MC groups — per-session calls would put O(sessions) tiny
-        # dispatches back on the tick.
-        if stu_ks:
-            idx = jnp.asarray([offsets[k] for k in stu_ks])
-            if self.kind == "classifier":
-                batched = _distill.classifier_student_summary(
-                    self.student, states[-1][0][idx])
-            else:
-                batched = _distill.autoencoder_student_summary(
-                    self.student, dec_out[idx],
-                    getattr(self.cfg, "heteroscedastic", True))
-            for j, k in enumerate(stu_ks):
-                summaries[k] = type(batched)(*(v[j] for v in batched))
-
-        # Windowed-decoder AEs reconstruct only min(L, W) positions per chunk
-        # — the valid slice is capped by the decode window, not the chunk.
-        win = getattr(self.cfg, "decode_window", None)
-        results: dict[str, ChunkResult] = {}
-        for k, (sess, L) in enumerate(zip(sessions, lens)):
-            sl = slice(offsets[k], offsets[k] + s_list[k])
-            if self.kind == "classifier":
-                summary = summaries[k]
-            else:
-                valid = L if win is None else min(L, win)
-                summary = RegressionSummary(
-                    *(v[:valid] for v in summaries[k]))
-            sess.state = [tuple(part[sl] for part in layer)
-                          for layer in states]
-            sess.steps += L
-            sess.chunks += 1
-            results[sess.sid] = ChunkResult(sid=sess.sid, length=L,
-                                            steps_total=sess.steps,
-                                            summary=summary)
-
-        self._last_served_chains = {sess.sid: si for sess, si
-                                    in zip(sessions, s_list)}
-        self._last_student_rows = {sessions[k].sid: 1 for k in stu_ks}
-        reclaimed = self._early_exit(sessions, lens, s_list, offsets, outs,
-                                     win)
-        # Escalation runs *after* state writeback: grow() tiles the carry
-        # the tick just stored, so the regrown chains resume exactly the
-        # student's post-chunk state.
-        escalations = self._escalate(sessions, results)
-
-        # Control-plane observables (host wall-clock; on CPU interpret the
-        # dispatch is effectively synchronous, on TPU it's a dispatch proxy).
-        dur = time.perf_counter() - t_start
-        live_steps = int(sum(lens))
-        live_chain_steps = int(sum(L * si for L, si in zip(lens, s_list)))
+        with _spans.tick(self.tick) as rec:
+            with rec.phase("engine.drain"):
+                self._drain()  # tick boundary: freed rows feed the wait-list
+            if not chunks:
+                return {}
+            results, counts = self._serve(chunks, rec)
+        # Host time of step(), drain included.  The device work it
+        # dispatched may still run when it returns: it ends where the
+        # caller fetches the results.
+        dur = rec.duration_s
         m = TickMetrics(
-            tick=self.tick, capacity=int(t_max), n_chunks=len(sessions),
-            live_rows=live_chains, batch_rows=nb,
-            queue_depth=len(self.queue), live_steps=live_steps,
-            live_chain_steps=live_chain_steps,
-            padded_steps=nb * int(t_max),
-            pad_waste=1.0 - live_chain_steps / (nb * int(t_max)),
+            tick=self.tick, **counts, queue_depth=len(self.queue),
             duration_s=dur,
-            tokens_per_sec=live_chain_steps / dur if dur > 0 else 0.0,
-            shards=self._shards, queue_wait_s=queue_wait_s,
-            compiles=stack_compile_count() - compiles_before,
+            tokens_per_sec=(counts["live_chain_steps"] / dur
+                            if dur > 0 else 0.0),
+            shards=self._shards, compiles=rec.compiles,
+            phase_s=rec.phase_s, gc_s=rec.gc_s,
             dropped=self._take_dropped(),
-            active_chains=self.store.active_chains,
-            reclaimed_rows=reclaimed,
-            student_rows=len(stu_ks), escalations=escalations)
+            active_chains=self.store.active_chains)
         self.metrics_sink.emit(m)
         self.tick += 1
         return results
+
+    def _serve(self, chunks, rec) -> tuple[dict[str, ChunkResult], dict]:
+        """The body of :meth:`step` after the drain, phase by phase, each
+        phase a span of the tick's record ``rec``.
+
+        Returns the results and the tick's counts for its
+        :class:`TickMetrics`.
+        """
+        # Head-of-line admission delay *after* the drain: how long the
+        # oldest stream that still couldn't get a row has been waiting.
+        queue_wait_s = self.queue.oldest_wait_s()
+        with rec.phase("engine.stage"):
+            sessions, xs, lens = [], [], []
+            for sid, chunk in chunks.items():
+                sess = self.store.get(sid)
+                x = np.asarray(chunk)
+                if x.ndim == 1:
+                    x = x[:, None]
+                if x.ndim != 2 or x.shape[0] < 1:
+                    raise ValueError(f"chunk for {sid!r} must be [t>=1, "
+                                     f"input_dim], got shape {tuple(x.shape)}")
+                sessions.append(sess)
+                xs.append(x)
+                lens.append(x.shape[0])
+            # Per-session chain counts — S is session state, not an engine
+            # constant.  With every session at the ceiling (the threshold-off
+            # default) the layout below is byte-identical to the static-S
+            # engine's; sharded launches require exactly that (whole sessions
+            # per shard is only well-defined with one S).
+            s_list = [int(sess.rows.shape[0]) for sess in sessions]
+            if self._shards > 1 and any(si != self.n_samples
+                                        for si in s_list):
+                raise ValueError(
+                    "sharded launches need every session at the engine "
+                    f"ceiling ({self.n_samples} chains); got {s_list} — "
+                    "per-session S would straddle shard boundaries")
+
+            if self._scheduler is not None:
+                t_max = self._scheduler.plan(lens)
+            elif self.chunk_capacity is not None:
+                if max(lens) > self.chunk_capacity:
+                    raise ValueError(f"chunk of {max(lens)} steps exceeds "
+                                     f"chunk_capacity={self.chunk_capacity}")
+                t_max = self.chunk_capacity
+            else:
+                t_max = max(lens)
+            dtype = xs[0].dtype
+            slots = self._slot_count(len(sessions))
+            # Launch size: fixed-shape modes always budget ceiling chains per
+            # slot — retired chains become tail padding and the one-graph
+            # guarantee survives early exit.  Dynamic mode launches exactly the
+            # live chains, so retirement shrinks the actual compute.
+            live_chains = sum(s_list)
+            nb = slots * self.n_samples if (self._fixed or self._shards > 1) \
+                else live_chains
+            n_pad = nb - live_chains
+            # Batch assembly stages in host numpy — one device transfer per
+            # operand per tick, not O(sessions) tiny dispatches.
+            # Session-major, chain-minor: session k's chains pack at
+            # offsets[k], matching the concatenated per-session mask rows
+            # (offset k*S when uniform).
+            x_host = np.zeros((nb, t_max, xs[0].shape[1]), dtype)
+            rows_host = np.zeros((nb,), np.uint32)
+            lens_host = np.ones((nb,), np.int32)
+            offsets, off = [], 0
+            for x, L, sess, si in zip(xs, lens, sessions, s_list):
+                sl = slice(off, off + si)
+                offsets.append(off)
+                x_host[sl, :L] = x[None]
+                rows_host[sl] = np.asarray(sess.rows)
+                lens_host[sl] = L
+                off += si
+            x_batch = jnp.asarray(x_host)
+            rows = jnp.asarray(rows_host)
+            lengths = jnp.asarray(lens_host)
+        with rec.phase("engine.carry_gather"):
+            initial_state = self._gather_states(sessions, dtype, n_pad)
+
+        with rec.phase("engine.launch"):
+            outs, states = self._apply(x_batch, rows, lengths, initial_state)
+            if self._shards > 1:
+                # The summaries below run op by op; on a sharded array XLA
+                # partitions each op and may round a reduction differently
+                # from one device (ulp-level on a TPU).  The outputs are
+                # small, so gather them onto one device: sharded ==
+                # unsharded, bit for bit.
+                dev = self.mesh.devices.flat[0]
+                outs = tuple(None if o is None else jax.device_put(o, dev)
+                             for o in outs)
+            if self.kind == "classifier":
+                (logits,) = outs
+            else:
+                mean, log_var, dec_out = outs
+
+        with rec.phase("engine.summarize"):
+            # Batched summaries over [s, group, ...] — per-session results
+            # are indexed out, not recomputed per session.  A uniform tick
+            # (the common case, and always when the threshold is off) is one
+            # reshape of the contiguous live prefix — the static engine's
+            # exact op sequence.  Ragged ticks group sessions by chain count
+            # (staged halving keeps distinct counts at most log2(S)+1) and
+            # gather each group's rows; values are launch-layout-invariant
+            # either way.
+            # Student sessions sit outside the chain-axis estimator entirely:
+            # their single deterministic row is decoded through the student
+            # heads below, and only the MC sessions group.
+            k_n = len(sessions)
+            summaries: list = [None] * k_n
+            stu_ks = [k for k in range(k_n) if sessions[k].mode == "student"]
+            mc_ks = [k for k in range(k_n) if sessions[k].mode != "student"]
+            mc_s = [s_list[k] for k in mc_ks]
+            groups = ([(s_list[0], list(range(k_n)))]
+                      if not stu_ks and len(set(s_list)) == 1
+                      else sorted({si: [k for k in mc_ks if s_list[k] == si]
+                                   for si in set(mc_s)}.items()))
+            for si, ks in groups:
+                if len(ks) == k_n:
+                    sel = lambda a: a.reshape((-1, si) + a.shape[1:])[:k_n]  # noqa: E731
+                else:
+                    idx = jnp.asarray(np.concatenate(
+                        [np.arange(offsets[k], offsets[k] + si) for k in ks]))
+                    sel = lambda a: a[idx].reshape((len(ks), si) + a.shape[1:])  # noqa: E731
+                if self.kind == "classifier":
+                    per_chain = jnp.swapaxes(sel(logits), 0, 1)
+                    batched = classification_summary(
+                        per_chain.astype(jnp.float32))
+                    for j, k in enumerate(ks):
+                        summaries[k] = ClassificationSummary(
+                            *(v[j] for v in batched))
+                else:
+                    mu = jnp.swapaxes(sel(mean), 0, 1)
+                    lv = (None if log_var is None
+                          else jnp.swapaxes(sel(log_var), 0, 1))
+                    batched = regression_summary(
+                        mu.astype(jnp.float32),
+                        None if lv is None else lv.astype(jnp.float32))
+                    for j, k in enumerate(ks):
+                        summaries[k] = RegressionSummary(
+                            *(v[j] for v in batched))
+
+            # Distilled fast path: a student session's summary comes from the
+            # student heads on its one deterministic row's features — h_T for
+            # the classifier, the decoder hidden sequence for the autoencoder.
+            # One batched head call over every student row, indexed out like
+            # the MC groups — per-session calls would put O(sessions) tiny
+            # dispatches back on the tick.
+            if stu_ks:
+                idx = jnp.asarray([offsets[k] for k in stu_ks])
+                if self.kind == "classifier":
+                    batched = _distill.classifier_student_summary(
+                        self.student, states[-1][0][idx])
+                else:
+                    batched = _distill.autoencoder_student_summary(
+                        self.student, dec_out[idx],
+                        getattr(self.cfg, "heteroscedastic", True))
+                for j, k in enumerate(stu_ks):
+                    summaries[k] = type(batched)(*(v[j] for v in batched))
+
+        with rec.phase("engine.writeback"):
+            # Windowed-decoder AEs reconstruct only min(L, W) positions per
+            # chunk — the valid slice is capped by the decode window, not the
+            # chunk.
+            win = getattr(self.cfg, "decode_window", None)
+            results: dict[str, ChunkResult] = {}
+            for k, (sess, L) in enumerate(zip(sessions, lens)):
+                sl = slice(offsets[k], offsets[k] + s_list[k])
+                if self.kind == "classifier":
+                    summary = summaries[k]
+                else:
+                    valid = L if win is None else min(L, win)
+                    summary = RegressionSummary(
+                        *(v[:valid] for v in summaries[k]))
+                sess.state = [tuple(part[sl] for part in layer)
+                              for layer in states]
+                sess.steps += L
+                sess.chunks += 1
+                results[sess.sid] = ChunkResult(sid=sess.sid, length=L,
+                                                steps_total=sess.steps,
+                                                summary=summary)
+
+            self._last_served_chains = {sess.sid: si for sess, si
+                                        in zip(sessions, s_list)}
+            self._last_student_rows = {sessions[k].sid: 1 for k in stu_ks}
+        with rec.phase("engine.early_exit"):
+            reclaimed = self._early_exit(sessions, lens, s_list, offsets,
+                                         outs, win)
+        # Escalation runs *after* state writeback: grow() tiles the carry
+        # the tick just stored, so the regrown chains resume exactly the
+        # student's post-chunk state.
+        with rec.phase("engine.escalate"):
+            escalations = self._escalate(sessions, results)
+
+        live_chain_steps = int(sum(L * si for L, si in zip(lens, s_list)))
+        counts = dict(
+            capacity=int(t_max), n_chunks=len(sessions),
+            live_rows=live_chains, batch_rows=nb,
+            live_steps=int(sum(lens)), live_chain_steps=live_chain_steps,
+            padded_steps=nb * int(t_max),
+            pad_waste=1.0 - live_chain_steps / (nb * int(t_max)),
+            queue_wait_s=queue_wait_s, reclaimed_rows=reclaimed,
+            student_rows=len(stu_ks), escalations=escalations)
+        return results, counts
 
     def _early_exit(self, sessions, lens, s_list, offsets, outs, win) -> int:
         """Retire surplus chains of prefix-converged sessions (one stage).

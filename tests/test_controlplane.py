@@ -333,7 +333,7 @@ class TestScheduler:
 
     def test_tick_metrics_thread_queue_wait_and_compiles(self):
         # hidden=6 gives this test its own jit shape family, so the first
-        # tick *must* register fresh stack compiles whatever ran before.
+        # tick *must* register fresh backend compiles whatever ran before.
         cfg, params = _cfg_params(s=5, hidden=6)
         eng = StreamingEngine(params, cfg, max_sessions=1, chunk_capacity=4)
         eng.open_session("a")
