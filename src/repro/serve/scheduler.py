@@ -98,6 +98,10 @@ class TickMetrics:
     escalations: int = 0       # student sessions that crossed the
                                # uncertainty threshold this tick and regrew
                                # to S fresh MC chains (store.grow)
+    carry_layouts_new: int = 0  # carry gather/split layouts the engine ran
+                                # for the first time this tick (each a
+                                # compiled program); 0 once a fixed-shape
+                                # stream has seen every tick size
     tenant: str | None = None  # owning tenant when the record came from a
                                # FleetEngine tick (None: single-tenant
                                # engine); summarize() groups on it

@@ -39,6 +39,7 @@ sessions per shard, and per-tick metrics flow through a pluggable
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 from collections import deque
 from typing import Any, Mapping, Protocol, Sequence, runtime_checkable
@@ -70,6 +71,47 @@ class ChunkResult:
     steps_total: int           # timesteps consumed by the session so far
     summary: Any               # ClassificationSummary | RegressionSummary
                                # (leading batch axis squeezed away)
+
+
+# ---------------------------------------------------------------------------
+# The tick's carry path — one compiled call each way
+# ---------------------------------------------------------------------------
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _concat_carries(per_session, n_pad: int, part_specs):
+    """Batch-aligned layer states from per-session carries, in one program.
+
+    ``per_session[k][layer][part]`` is session ``k``'s ``[S_k, H]`` part;
+    ``part_specs[layer]`` gives each part's ``(hidden, dtype)``, which
+    sizes the ``n_pad`` zero rows built here.  Concatenation is exact, so
+    the result is bit for bit what the per-part eager concatenates gave.
+    """
+    layers = []
+    for li, specs in enumerate(part_specs):
+        layer = []
+        for pi, (hid, dt) in enumerate(specs):
+            acc = [sess[li][pi] for sess in per_session]
+            if n_pad:
+                acc.append(jnp.zeros((n_pad, hid), dt))
+            layer.append(jnp.concatenate(acc))
+        layers.append(tuple(layer))
+    return layers
+
+
+@functools.partial(jax.jit, static_argnums=(1,))
+def _split_carries(states, counts):
+    """Per-session carries sliced out of the launch's layer states.
+
+    Session ``k`` holds the ``counts[k]`` rows after the previous sessions'
+    (session-major, chain-minor); the pad rows after them are dropped.
+    Returns, per session, the per-layer part tuples ``Session.state`` holds.
+    """
+    out, off = [], 0
+    for si in counts:
+        out.append([tuple(part[off:off + si] for part in layer)
+                    for layer in states])
+        off += si
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -346,6 +388,11 @@ class StreamingEngine:
         # ticks in admit()/close_session() — lands in the next tick's
         # ``dropped`` count.
         self._dropped_unreported = 0
+        # The carry path's compiled layouts (see _carry_call) and the zero
+        # carries fresh sessions pass, by (rows, hidden, dtype).
+        self._carry_layouts: set = set()
+        self._carry_layouts_new = 0
+        self._zero_carries: dict = {}
 
     # -- session lifecycle ---------------------------------------------------
     def open_session(self, sid: str, *, n_samples: int | None = None,
@@ -686,6 +733,7 @@ class StreamingEngine:
         # Head-of-line admission delay *after* the drain: how long the
         # oldest stream that still couldn't get a row has been waiting.
         queue_wait_s = self.queue.oldest_wait_s()
+        self._carry_layouts_new = 0
         with rec.phase("engine.stage"):
             sessions, xs, lens = [], [], []
             for sid, chunk in chunks.items():
@@ -838,17 +886,16 @@ class StreamingEngine:
             # chunk — the valid slice is capped by the decode window, not the
             # chunk.
             win = getattr(self.cfg, "decode_window", None)
+            carries = self._carry_call(_split_carries, states, tuple(s_list))
             results: dict[str, ChunkResult] = {}
             for k, (sess, L) in enumerate(zip(sessions, lens)):
-                sl = slice(offsets[k], offsets[k] + s_list[k])
                 if self.kind == "classifier":
                     summary = summaries[k]
                 else:
                     valid = L if win is None else min(L, win)
                     summary = RegressionSummary(
                         *(v[:valid] for v in summaries[k]))
-                sess.state = [tuple(part[sl] for part in layer)
-                              for layer in states]
+                sess.state = carries[k]
                 sess.steps += L
                 sess.chunks += 1
                 results[sess.sid] = ChunkResult(sid=sess.sid, length=L,
@@ -875,7 +922,8 @@ class StreamingEngine:
             padded_steps=nb * int(t_max),
             pad_waste=1.0 - live_chain_steps / (nb * int(t_max)),
             queue_wait_s=queue_wait_s, reclaimed_rows=reclaimed,
-            student_rows=len(stu_ks), escalations=escalations)
+            student_rows=len(stu_ks), escalations=escalations,
+            carry_layouts_new=self._carry_layouts_new)
         return results, counts
 
     def _early_exit(self, sessions, lens, s_list, offsets, outs, win) -> int:
@@ -1022,6 +1070,11 @@ class StreamingEngine:
         materialized: an all-fresh first tick must present the same jit
         pytree as every later tick, or the one-graph guarantee would break
         on tick two.
+
+        Which sessions are fresh is read here, on the host; the
+        concatenation is one compiled call over every layer and part
+        (:func:`_concat_carries`).  A fresh session passes cached zeros of
+        its resumed shape, so freshness never enters the compiled layout.
         """
         if all(sess.fresh for sess in sessions) and not self._fixed:
             return None
@@ -1034,26 +1087,44 @@ class StreamingEngine:
             c_dtype = jnp.float32
         else:
             c_dtype = dtype if self.backend == "reference" else jnp.float32
-        part_dtypes = (dtype,) if self.cell == "gru" else (dtype, c_dtype)
-        hiddens = (self._encoder_hiddens())
-        layers = []
-        for li, hid in enumerate(hiddens):
-            parts = [[] for _ in part_dtypes]
-            for sess in sessions:
-                if sess.fresh:
-                    # Zeros sized by the session's *own* chain count — the
-                    # batch layout packs per-session S, not the ceiling.
-                    for acc, dt in zip(parts, part_dtypes):
-                        acc.append(jnp.zeros(
-                            (int(sess.rows.shape[0]), hid), dt))
-                else:
-                    for acc, part in zip(parts, sess.state[li]):
-                        acc.append(part)
-            if n_pad:
-                for acc, dt in zip(parts, part_dtypes):
-                    acc.append(jnp.zeros((n_pad, hid), dt))
-            layers.append(tuple(jnp.concatenate(acc) for acc in parts))
-        return layers
+        part_dtypes = tuple(np.dtype(dt) for dt in (
+            (dtype,) if self.cell == "gru" else (dtype, c_dtype)))
+        part_specs = tuple(tuple((hid, dt) for dt in part_dtypes)
+                           for hid in self._encoder_hiddens())
+        per_session = []
+        for sess in sessions:
+            if sess.fresh:
+                # Zeros sized by the session's *own* chain count — the
+                # batch layout packs per-session S, not the ceiling.
+                rows = int(sess.rows.shape[0])
+                per_session.append(tuple(
+                    tuple(self._zero_carry(rows, hid, dt) for hid, dt in specs)
+                    for specs in part_specs))
+            else:
+                per_session.append(tuple(tuple(layer)
+                                         for layer in sess.state))
+        return self._carry_call(_concat_carries, tuple(per_session),
+                                int(n_pad), part_specs)
+
+    def _zero_carry(self, rows: int, hidden: int, dtype):
+        key = (rows, hidden, dtype)
+        if key not in self._zero_carries:
+            self._zero_carries[key] = jnp.zeros((rows, hidden), dtype)
+        return self._zero_carries[key]
+
+    def _carry_call(self, fn, arrays, *static):
+        """Call a carry-path program; count a layout this engine has not
+        run before into the tick's ``carry_layouts_new``.
+
+        A layout is the static arguments and each array's shape and dtype:
+        what the compiled program is keyed by.
+        """
+        key = (fn.__name__, static, tuple(
+            (a.shape, a.dtype) for a in jax.tree.leaves(arrays)))
+        if key not in self._carry_layouts:
+            self._carry_layouts.add(key)
+            self._carry_layouts_new += 1
+        return fn(arrays, *static)
 
     def _encoder_hiddens(self):
         if self.kind == "classifier":

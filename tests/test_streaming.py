@@ -10,6 +10,7 @@ bit-stable across launch sizes, splits, batch composition and backends
 """
 
 import dataclasses
+import json
 
 import jax
 import jax.numpy as jnp
@@ -18,7 +19,9 @@ import pytest
 
 from repro.core import autoencoder as ae, classifier as clf, distill, mcd, rnn
 from repro.core.uncertainty import classification_summary
-from repro.serve import (CapacityError, SessionStore, StreamingEngine)
+from repro.kernels import quantize
+from repro.serve import (CapacityError, JsonlSink, SessionStore,
+                         StreamingEngine, prewarm)
 
 import conformance
 
@@ -765,3 +768,192 @@ class TestStreamingEngineGru:
                                       np.asarray(qa.summary.mean))
         np.testing.assert_array_equal(np.asarray(ra.summary.total),
                                       np.asarray(qa.summary.total))
+
+
+class _EagerCarryEngine(StreamingEngine):
+    """The carry path as per-part eager ops: one ``zeros`` per fresh
+    session part and pad, one ``concatenate`` per layer part, one slice per
+    served session part.  The oracle the compiled path must match."""
+
+    def _gather_states(self, sessions, dtype, n_pad=0):
+        if all(sess.fresh for sess in sessions) and not self._fixed:
+            return None
+        if self.precision is not None:
+            dtype = quantize.activation_dtype(self.precision, dtype)
+            c_dtype = jnp.float32
+        else:
+            c_dtype = dtype if self.backend == "reference" else jnp.float32
+        part_dtypes = (dtype,) if self.cell == "gru" else (dtype, c_dtype)
+        layers = []
+        for li, hid in enumerate(self._encoder_hiddens()):
+            parts = [[] for _ in part_dtypes]
+            for sess in sessions:
+                if sess.fresh:
+                    for acc, dt in zip(parts, part_dtypes):
+                        acc.append(jnp.zeros(
+                            (int(sess.rows.shape[0]), hid), dt))
+                else:
+                    for acc, part in zip(parts, sess.state[li]):
+                        acc.append(part)
+            if n_pad:
+                for acc, dt in zip(parts, part_dtypes):
+                    acc.append(jnp.zeros((n_pad, hid), dt))
+            layers.append(tuple(jnp.concatenate(acc) for acc in parts))
+        return layers
+
+    def _carry_call(self, fn, states, counts):
+        assert fn.__name__ == "_split_carries"   # the gather is above
+        out, off = [], 0
+        for si in counts:
+            sl = slice(off, off + si)
+            out.append([tuple(part[sl] for part in layer)
+                        for layer in states])
+            off += si
+        return out
+
+
+def _carry_cfg(model, cell):
+    p = mcd.MCDConfig(p=0.125, placement="YN", n_samples=4, seed=3)
+    if model == "clf":
+        cfg = clf.ClassifierConfig(hidden=8, num_layers=2, num_classes=4,
+                                   cell=cell, mcd=p)
+        return cfg, clf.init(jax.random.key(0), cfg)
+    cfg = ae.AutoencoderConfig(hidden=8, num_layers=1, cell=cell, mcd=p)
+    return cfg, ae.init(jax.random.key(0), cfg)
+
+
+def _mixed_ticks(eng, sig):
+    """A fresh session joins a resumed one mid-stream."""
+    eng.open_session("a")
+    yield {"a": sig[0][:3]}
+    eng.open_session("b")
+    yield {"a": sig[0][3:5], "b": sig[1][:4]}
+    yield {"b": sig[1][4:6], "a": sig[0][5:9]}
+
+
+def _student_ticks(eng, sig):
+    """A student row co-batched with MC sessions, fresh and resumed."""
+    eng.open_session("s", mode="student")
+    eng.open_session("a")
+    yield {"s": sig[0][:3], "a": sig[1][:2]}
+    eng.open_session("b")
+    yield {"a": sig[1][2:6], "b": sig[2][:5], "s": sig[0][3:4]}
+    yield {"s": sig[0][4:8], "b": sig[2][5:7]}
+
+
+# name: (model, cell, engine options, ticks)
+_CARRY_CASES = {
+    "clf-lstm-fixed": ("clf", "lstm", dict(chunk_capacity=8), _mixed_ticks),
+    "clf-gru-dynamic": ("clf", "gru", {}, _mixed_ticks),
+    "ae-lstm-dynamic": ("ae", "lstm", {}, _mixed_ticks),
+    "ae-gru-fixed": ("ae", "gru", dict(chunk_capacity=8), _mixed_ticks),
+    "clf-lstm-bf16": ("clf", "lstm", dict(precision="bf16"), _mixed_ticks),
+    # every tick retires half of each session's chains: a's 4 -> 2 -> 1
+    # while b joins at 4, so the later ticks pack ragged S
+    "clf-lstm-early-exit": ("clf", "lstm",
+                            dict(early_exit_threshold=1e9), _mixed_ticks),
+    "clf-lstm-student": ("clf", "lstm", dict(chunk_capacity=8, student=True),
+                         _student_ticks),
+}
+
+
+class TestCompiledCarryPath:
+    """The tick's carry path runs as one compiled gather and one compiled
+    split; concatenation and slicing are exact, so results and stored
+    carries match the eager per-part path bit for bit."""
+
+    @staticmethod
+    def _serve(engine_cls, model, cell, opts, ticks):
+        cfg, params = _carry_cfg(model, cell)
+        opts = dict(opts)
+        if opts.pop("student", False):
+            opts["student"] = distill.init_student(jax.random.key(1), cfg,
+                                                   params)
+        eng = engine_cls(params, cfg, max_sessions=3, **opts)
+        sig = [np.asarray(jax.random.normal(jax.random.key(10 + k), (9, 1)),
+                          np.float32) for k in range(3)]
+        trail = []
+        for chunks in ticks(eng, sig):
+            res = eng.step(chunks)
+            trail.append((
+                {sid: [np.asarray(v) for v in r.summary]
+                 for sid, r in res.items()},
+                {sess.sid: [[np.asarray(p) for p in layer]
+                            for layer in sess.state]
+                 for sess in eng.store.sessions()},
+                [eng._last_served_chains[sid] for sid in chunks]))
+        return trail
+
+    @pytest.mark.parametrize("case", sorted(_CARRY_CASES))
+    def test_compiled_equals_eager_bit_identical(self, case):
+        model, cell, opts, ticks = _CARRY_CASES[case]
+        got = self._serve(StreamingEngine, model, cell, opts, ticks)
+        want = self._serve(_EagerCarryEngine, model, cell, opts, ticks)
+        assert len(got) == len(want) == 3
+        for (g_res, g_state, g_s), (w_res, w_state, w_s) in zip(got, want):
+            assert g_s == w_s
+            assert g_res.keys() == w_res.keys()
+            assert g_state.keys() == w_state.keys()
+            for sid in w_res:
+                for g, w in zip(g_res[sid], w_res[sid]):
+                    assert g.dtype == w.dtype
+                    np.testing.assert_array_equal(g, w)
+            for sid in w_state:
+                for g_layer, w_layer in zip(g_state[sid], w_state[sid]):
+                    assert len(g_layer) == len(w_layer)
+                    for g, w in zip(g_layer, w_layer):
+                        assert g.dtype == w.dtype
+                        np.testing.assert_array_equal(g, w)
+        if case == "clf-lstm-early-exit":
+            # chains served per session: the later ticks pack ragged S
+            assert [s for _, _, s in got] == [[4], [2, 4], [2, 1]]
+
+    def test_fixed_shapes_compile_nothing_after_every_size(self, tmp_path):
+        """After prewarm and one tick of every size, a fixed-shape stream
+        compiles nothing and runs no new carry layout, whatever the chunk
+        lengths, the tick's sessions or their order."""
+        cfg, params = _carry_cfg("clf", "lstm")
+        trail = tmp_path / "ticks.jsonl"
+        eng = StreamingEngine(params, cfg, max_sessions=3, chunk_capacity=4,
+                              metrics_sink=JsonlSink(str(trail)))
+        for sid in "abc":
+            eng.open_session(sid)
+        prewarm(eng)
+        x = np.ones((4, 1), np.float32)
+        for k in (3, 2, 1):                      # every size, largest first
+            eng.step({sid: x for sid in "abc"[:k]})
+            # a new session count is a new gather and a new split
+            assert eng.last_metrics.carry_layouts_new == 2
+        stream = [{"b": x[:2]}, {"c": x[:1], "a": x}, {"c": x, "a": x[:3],
+                                                         "b": x[:1]},
+                  {"a": x[:2], "b": x}, {"a": x[:1]}]
+        for chunks in stream:
+            eng.step(chunks)
+            m = eng.last_metrics
+            assert (m.compiles, m.carry_layouts_new) == (0, 0), m
+        eng.metrics_sink.close()
+        counts = [json.loads(line)["carry_layouts_new"]
+                  for line in trail.read_text().splitlines()]
+        assert counts == [2, 2, 2] + [0] * len(stream)
+
+    def test_cleared_state_gathers_zeros(self):
+        """A session whose ``state`` is set to None before the gather reads
+        as fresh: zeros in its rows, its neighbour's carry untouched (the
+        contract a planted carry-unchanged fault relies on)."""
+        cfg, params = _carry_cfg("clf", "lstm")
+        eng = StreamingEngine(params, cfg, max_sessions=3, chunk_capacity=4)
+        a, b = eng.open_session("a"), eng.open_session("b")
+        x = np.ones((4, 1), np.float32)
+        eng.step({"a": x, "b": x})
+        kept = b.state
+        a.state = None
+        layers = eng._gather_states([a, b], np.float32, n_pad=4)
+        assert len(layers) == cfg.num_layers
+        for layer, b_layer in zip(layers, kept):
+            for part, b_part in zip(layer, b_layer):
+                assert part.shape == (12, cfg.hidden)
+                np.testing.assert_array_equal(np.asarray(part[:4]), 0.0)
+                np.testing.assert_array_equal(np.asarray(part[4:8]),
+                                              np.asarray(b_part))
+                assert np.any(np.asarray(b_part) != 0.0)
+                np.testing.assert_array_equal(np.asarray(part[8:]), 0.0)
