@@ -2,10 +2,14 @@
 
 Everything a cell needs is found by name from ``BENCHMARK.json``: the
 configuration file of its ``config``, the traffic file
-``bench/traffic/<traffic>.json``, the reference module that the
-configuration file names (``bench/reference/<reference>.py``) and one reader
-per metric (``bench/metrics/<metric>.py``).  A new cell, configuration or
-metric is new files and a new entry, never an edit here.
+``bench/traffic/<traffic>.json``, the traffic's generator
+(``bench/generators/<generator>.py``, see ``bench/loadgen.py``), the model
+and reference modules that the configuration file names
+(``bench/models/<model>.py``, ``bench/reference/<reference>.py``) and one
+reader per metric (``bench/metrics/<metric>.py``).  The model module draws
+the bank the chunks are cut from and may add engine keywords.  A new cell,
+configuration, family, traffic generator or metric is new files and a new
+entry, never an edit here.
 
 A run, in order:
 
@@ -15,13 +19,15 @@ A run, in order:
    prewarmed (``repro.serve.scheduler.prewarm``), then one tick of every
    size from ``sessions`` down to 1, so that each tick size the traffic can
    make has compiled its eager summary ops before the window;
-2. the traffic's own open-loop warm-up (``warmup_s``), then the window of
-   ``--seconds``: each session's beat falls due on its schedule; whenever
-   chunks are due the loop hands the oldest due chunk of every such session
-   to one ``engine.step`` and fetches the tick's summaries to the host
+2. the traffic's own warm-up (``warmup_s``), then the window of
+   ``--seconds``: each session's chunks fall due on its schedule, at fixed
+   times or chained to the session's previous result; whenever chunks are
+   due the loop hands the oldest due chunk of every such session to one
+   ``engine.step`` and fetches the tick's summaries to the host
    (``jax.device_get``).  A chunk's latency runs from its due time to that
    fetch.  Chunks due in the window and still queued when it closes are
-   served and timed after it;
+   served and timed after it; a chained chunk that would fall due after
+   the window closes is not sent, nor any later chunk of its session;
 3. the program's state is read (peak memory) and freed, and the reference
    replays every session's chunks, in order, from the same seed, once with
    the carry and once without it; the comparison decides ``correct``.
@@ -49,7 +55,7 @@ from pathlib import Path
 
 import numpy as np
 
-from bench import ecg, loadgen, roofline, trace_reduce
+from bench import loadgen, roofline, trace_reduce
 
 ROOT = Path(__file__).resolve().parents[1]
 BENCH = ROOT / "bench"
@@ -99,6 +105,10 @@ def model(cfg: dict):
     return _module("models", cfg["model"])
 
 
+def generator(traffic: dict):
+    return _module("generators", traffic.get("generator", "periodic"))
+
+
 def derive_seeds(seed: int) -> tuple[int, int, int]:
     """Weight seed, mask seed and traffic seed, all from ``--seed``."""
     ss = np.random.SeedSequence(int(seed) % (1 << 64))
@@ -128,17 +138,20 @@ def build_engine(cfg: dict, traffic: dict, w_seed: int, mc_seed: int,
 
     mc = mcd.MCDConfig(p=cfg["p"], placement=cfg["placement"],
                        n_samples=cfg["n_samples"], seed=mc_seed)
-    init, mcfg = model(cfg).build(cfg, mc)
+    family = model(cfg)
+    init, mcfg = family.build(cfg, mc)
     params = jax.jit(init, static_argnums=1)(jax.random.key(w_seed), mcfg)
     mesh = None
     if chips > 1:
         from repro.launch.mesh import make_data_mesh
         mesh = make_data_mesh(chips)
     n = int(traffic["sessions"])
+    options = (family.engine_options(cfg, traffic)
+               if hasattr(family, "engine_options") else {})
     return StreamingEngine(params, mcfg, backend="pallas_seq",
                            max_sessions=int(traffic["max_sessions"]),
                            chunk_capacity=int(traffic["chunk_capacity"]),
-                           max_pending=n, mesh=mesh)
+                           max_pending=n, mesh=mesh, **options)
 
 
 class CompileCounter:
@@ -164,10 +177,14 @@ class Run:
     cell: Cell
     seconds: float
     window: tuple[float, float]     # host clock, start and end
-    due: np.ndarray                 # per chunk served in the open loop
+    due: np.ndarray                 # per chunk served after set-up
     submit: np.ndarray
     done: np.ndarray
     free: np.ndarray                # when the loop was last free before it
+    session: np.ndarray             # the session it came from
+    index: np.ndarray               # its entry in the session's schedule
+    steps: np.ndarray               # the steps it carried
+    schedule: loadgen.Schedule      # what the generator drew
     ticks: list                     # (start, end, chunks) per window tick
     tick_metrics: list              # the engine's TickMetrics, window ticks
     compiles_in_window: int
@@ -244,9 +261,9 @@ def _run(cell: Cell, seed: int, seconds: float, trace: bool, *, t_start,
     cfg, traffic = cell.cfg, cell.traffic
     w_seed, mc_seed, t_seed = derive_seeds(seed)
     rng = np.random.default_rng(t_seed)
-    bank = ecg.beat_bank(rng, int(traffic["beat_bank"]))
+    bank = model(cfg).chunk_bank(rng, cfg, traffic)
     warm = float(traffic["warmup_s"])
-    sched = loadgen.schedule(traffic, rng, warm + seconds)
+    sched = generator(traffic).schedule(traffic, rng, warm + seconds)
     n = int(traffic["sessions"])
     sids = [f"s{i:04d}" for i in range(n)]
     counter = CompileCounter()
@@ -265,14 +282,16 @@ def _run(cell: Cell, seed: int, seconds: float, trace: bool, *, t_start,
     prewarm(engine)
     phase("prewarm")
     spans = _Spans()
-    history = [[] for _ in range(n)]     # beat indices served, in order
+    history = [[] for _ in range(n)]     # (bank row, steps) served, in order
     served = [[] for _ in range(n)]      # host summaries, in order
     failed = 0
 
-    def tick(idx, beats):
+    def tick(idx, rows, lens):
+        """Serve bank ``rows`` (their first ``lens`` steps) to ``idx``."""
         nonlocal failed
         with spans("submit"):
-            chunks = {sids[i]: bank[b] for i, b in zip(idx, beats)}
+            chunks = {sids[i]: bank[b][:m]
+                      for i, b, m in zip(idx, rows, lens)}
         try:
             with spans("step"):
                 res = engine.step(chunks)
@@ -283,15 +302,15 @@ def _run(cell: Cell, seed: int, seconds: float, trace: bool, *, t_start,
                   file=sys.stderr)
             failed += len(idx)
             return False
-        for i, b in zip(idx, beats):
-            history[i].append(b)
+        for i, b, m in zip(idx, rows, lens):
+            history[i].append((b, m))
             served[i].append(host[sids[i]]._asdict())
         return True
 
     warmed = set() if warmed is None else warmed
     for k in range(n, 0, -1):             # every tick size, largest first
         if k not in warmed:
-            tick(list(range(k)), rng.integers(0, len(bank), k))
+            tick(list(range(k)), rng.integers(0, len(bank), k), [None] * k)
             warmed.add(k)
     phase("tick_sizes")
 
@@ -326,23 +345,29 @@ def _run(cell: Cell, seed: int, seconds: float, trace: bool, *, t_start,
                 _wait_until(until)
             continue
         due = next_due[ready]
-        beats = [sched.beats[i][nxt[i]] for i in ready]
+        rows = [sched.chunks[i][nxt[i]] for i in ready]
+        lens = [sched.lengths[i][nxt[i]] for i in ready]
         submit = clock()
-        ok = tick(list(ready), beats)
+        ok = tick(list(ready), rows, lens)
         done = clock()
         if w0 <= submit < w1:
             ticks.append((submit, done, len(ready)))
             tmetrics.append(engine.last_metrics)
-        for i, d in zip(ready, due):
-            recs.append((d, submit, done if ok else np.inf, free))
-            nxt[i] += 1
-            next_due[i] = (sched.due[i][nxt[i]] + t0
-                           if nxt[i] < sched.due[i].size else np.inf)
+        for i, d, b, m in zip(ready, due, rows, lens):
+            j = nxt[i]
+            recs.append((d, submit, done if ok else np.inf, free, i, j,
+                         len(bank[b][:m])))
+            nxt[i] = j = j + 1
+            if j < sched.due[i].size:   # its time, or this result + delay
+                t = max(sched.due[i][j] + t0, done + sched.after[i][j])
+                next_due[i] = t if t < w1 else np.inf
+            else:
+                next_due[i] = np.inf
         free = done
     counter.on = spans.on = False
     if trace:
         jax.profiler.stop_trace()
-    rec = np.array(recs, float).reshape(-1, 4)
+    rec = np.array(recs, float).reshape(-1, 7)
     mem = _peak_bytes(devices)
     kind = devices[0].device_kind
     del engine
@@ -350,7 +375,9 @@ def _run(cell: Cell, seed: int, seconds: float, trace: bool, *, t_start,
 
     result = Run(cell=cell, seconds=float(seconds), window=(w0, w1),
                  due=rec[:, 0], submit=rec[:, 1], done=rec[:, 2],
-                 free=rec[:, 3], ticks=ticks, tick_metrics=tmetrics,
+                 free=rec[:, 3], session=rec[:, 4].astype(int),
+                 index=rec[:, 5].astype(int), steps=rec[:, 6].astype(int),
+                 schedule=sched, ticks=ticks, tick_metrics=tmetrics,
                  compiles_in_window=counter.count, setup_s=setup_s,
                  device_kind=kind, chips=chips, memory_peak_bytes=mem,
                  failed=failed, setup_phases=phases)
@@ -391,9 +418,11 @@ def check(cell: Cell, w_seed: int, mc_seed: int, bank, history, served, *,
     Each configuration's reference module defines ``replay`` and ``gaps``;
     its file's ``limits`` give the limit of each number.  A chunk that was
     never served, or a value that is not finite, fails on its own.
+    ``history`` holds each session's served ``(bank row, steps)`` pairs,
+    ``steps`` None for a whole row.
     """
     ref_mod = reference(cell.cfg)
-    chunks = [bank[np.asarray(h, int)] for h in history]
+    chunks = served_chunks(bank, history)
     ref = ref_mod.replay(cell.cfg, w_seed, mc_seed, chunks)
     reset = ref_mod.replay(cell.cfg, w_seed, mc_seed, chunks, carry=False)
     numbers = dict(ref_mod.gaps(served, ref, reset))
@@ -405,6 +434,15 @@ def check(cell: Cell, w_seed: int, mc_seed: int, bank, history, served, *,
         for k, v in ref_mod.gaps(ctl, ref, reset).items():
             numbers[f"control:{matmul}:{k}"] = v
     return numbers
+
+
+def served_chunks(bank, history) -> list:
+    """Each session's chunks, in order, as the reference takes them: one
+    array of bank rows where every chunk is a whole row, else a list of
+    the chunks as served."""
+    if all(m is None for h in history for _, m in h):
+        return [bank[np.asarray([b for b, _ in h], int)] for h in history]
+    return [[bank[b][:m] for b, m in h] for h in history]
 
 
 def limits(cell: Cell) -> dict:

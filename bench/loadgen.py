@@ -1,19 +1,30 @@
-"""The one traffic generator: open-loop ECG sessions from a traffic file.
+"""What a traffic generator gives the harness: a schedule of chunks.
 
-Every session is a patient's monitor sending one 140-sample beat per
-heartbeat.  A traffic file fixes the parameters:
+A traffic file names its generator under ``"generator"`` (``periodic``
+where it names none), a module ``bench/generators/<name>.py`` that the
+harness finds by that name and that defines:
 
-* ``sessions``: concurrent sessions, all admitted before the window;
-* ``hr_bpm``: ``[lo, hi]`` heart rates.  The rates are the same evenly
-  spaced set for every seed (``lo + (hi - lo) * (i + 0.5) / sessions``), so
-  the offered load does not move with the seed; the seed only deals them
-  out to sessions;
-* ``beat_jitter``: each beat's period is ``60 / hr`` times ``1 + u``, ``u``
-  uniform in ``[-beat_jitter, beat_jitter]``;
-* ``beat_bank``: how many distinct beats the seed draws to choose from.
+* ``schedule(traffic, rng, horizon)``: a :class:`Schedule` of the chunks
+  the sessions send, drawn from ``rng`` alone, with every absolute due
+  time in ``[0, horizon)``;
+* ``offered_rate(traffic)``: the mean chunks per second the sessions
+  offer together;
+* ``period_s(traffic)``: the mean time between one session's chunks, the
+  deadline under which the knee sweep (``bench/sweep.py``) holds a p95.
 
-A session's first beat falls due at a uniform phase within its first
-period, so arrivals are spread from the start.
+A chunk is a row of the configuration's bank (``chunk_bank`` of its
+``bench/models/<model>.py``), or its first ``lengths[i][j]`` steps where
+that is not None.  A generator sees only the bank's size, from
+its traffic file: nothing of the program's answers feeds back, so the
+reference replays exactly the chunks that were served.
+
+A session's chunks go out in order, one in flight at a time.  Chunk ``j``
+of session ``i`` falls due at the later of ``due[i][j]`` seconds after the
+traffic's start and ``after[i][j]`` seconds after the session's chunk
+``j - 1`` came back to the host: ``due`` ``-inf`` with ``after`` ``think``
+is a step chained to the previous result, ``after`` 0 a turn that waits
+for the one before, ``after`` ``-inf`` a chunk at its fixed time.  A
+session's first chunk has a finite ``due``.
 """
 
 from __future__ import annotations
@@ -25,34 +36,17 @@ import numpy as np
 
 @dataclasses.dataclass
 class Schedule:
-    due: list[np.ndarray]     # per session: due times, seconds from start
-    beats: list[np.ndarray]   # per session: beat-bank index of each chunk
+    """Per session, one entry per chunk.  A generator may leave out
+    ``lengths`` (every chunk a whole row, ``None``) and ``after`` (no chunk
+    chained, ``-inf``); they are filled in here."""
 
+    due: list[np.ndarray]       # seconds from the start
+    chunks: list[np.ndarray]    # bank row
+    lengths: list | None = None     # steps, None for the whole row
+    after: list | None = None       # seconds after the chunk before came back
 
-def heart_rates(traffic: dict) -> np.ndarray:
-    n = int(traffic["sessions"])
-    lo, hi = traffic["hr_bpm"]
-    return lo + (hi - lo) * (np.arange(n) + 0.5) / n
-
-
-def offered_rate(traffic: dict) -> float:
-    """Mean chunks per second the sessions offer together."""
-    return float(np.sum(heart_rates(traffic) / 60.0))
-
-
-def schedule(traffic: dict, rng: np.random.Generator,
-             horizon: float) -> Schedule:
-    """Due times in ``[0, horizon)`` and the beat each chunk carries."""
-    hr = heart_rates(traffic)[rng.permutation(int(traffic["sessions"]))]
-    jitter = float(traffic["beat_jitter"])
-    due, beats = [], []
-    for rate in hr:
-        period = 60.0 / rate
-        n_max = int(horizon / (period * (1 - jitter))) + 2
-        steps = period * (1 + rng.uniform(-jitter, jitter, n_max))
-        t = rng.uniform(0, period) + np.concatenate([[0.0],
-                                                     np.cumsum(steps)])
-        t = t[t < horizon]
-        due.append(t)
-        beats.append(rng.integers(0, int(traffic["beat_bank"]), t.size))
-    return Schedule(due, beats)
+    def __post_init__(self):
+        if self.lengths is None:
+            self.lengths = [[None] * d.size for d in self.due]
+        if self.after is None:
+            self.after = [np.full(d.size, -np.inf) for d in self.due]

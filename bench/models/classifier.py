@@ -1,12 +1,15 @@
-"""The program's MC-dropout classifier as a cell builds and counts it.
+"""The program's MC-dropout classifier as a cell builds, feeds and counts it.
 
 A model family is one file here, found by the ``model`` of a configuration
 file: ``build`` makes the program's config and names its ``init``,
+``chunk_bank`` draws the rows the traffic's chunks are cut from,
 ``layer_widths`` gives the recurrent kernel's launches of one tick and
-``model_flops`` the matmul work of the chain-steps served.
+``model_flops`` the matmul work of the chain-steps served.  A family may
+also define ``engine_options(cfg, traffic)``, further keywords of
+``repro.serve.StreamingEngine``; this one needs none.
 """
 
-from bench import roofline
+from bench import ecg, roofline
 
 
 def build(cfg: dict, mc):
@@ -17,6 +20,11 @@ def build(cfg: dict, mc):
         input_dim=cfg["input_dim"], hidden=cfg["hidden"],
         num_layers=cfg["num_layers"], num_classes=cfg["num_classes"],
         cell=cfg["cell"], mcd=mc)
+
+
+def chunk_bank(rng, cfg: dict, traffic: dict):
+    """``beat_bank`` synthetic ECG beats ``[n, 140]``, float32."""
+    return ecg.beat_bank(rng, int(traffic["beat_bank"]))
 
 
 def layer_widths(cfg: dict) -> list[tuple[int, int]]:

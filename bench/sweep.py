@@ -3,18 +3,19 @@
     python bench/sweep.py --workload clf_ward_steady --counts 32,48,64,80,96
 
 Steps the session count in one process, each count one run of the cell's
-traffic (its heart rates, warm-up and chunk length, with ``sessions`` set
-to the count).  Every count launches at the largest count's shape
-(``max_sessions``), so the launch and each tick size compile once for the
-whole sweep; the padded rows cost the kernel a little more than the cell's
-own shape does, so the knee found is, if anything, low.  Prints per count the offered and
-served chunk rates, the p50 and p95 latency, the backlog (chunks due in the
-window whose summaries came after it closed) and the growth of latency
-(mean of the window's last quarter over its first).  The knee is the highest
-count whose p95 stays under the mean beat period with no growing backlog:
-backlog under one chunk per session and growth under 1.5.  ``--write``
-puts 4/5 of the knee, rounded down to a multiple of the cell's chips, into
-the cell's traffic file.
+traffic (its generator's parameters, warm-up and chunk length, with
+``sessions`` set to the count).  Every count launches at the largest count's
+shape (``max_sessions``), so the launch and each tick size compile once for
+the whole sweep; the padded rows cost the kernel a little more than the
+cell's own shape does, so the knee found is, if anything, low.  Prints per
+count the offered and served chunk rates, the p50 and p95 latency, the
+backlog (chunks due in the window whose summaries came after it closed) and
+the growth of latency (mean of the window's last quarter over its first).
+The knee is the highest count whose p95 stays under the generator's
+``period_s``, the mean time between one session's chunks, with no growing
+backlog: backlog under one chunk per session and growth under 1.5.
+``--write`` puts 4/5 of the knee, rounded down to a multiple of the cell's
+chips, into the cell's traffic file.
 """
 
 import argparse
@@ -30,7 +31,7 @@ def measure(cell, count: int, top: int, seed: int, seconds: float,
             warmed: set) -> dict:
     import numpy as np
 
-    from bench import harness, loadgen
+    from bench import harness
     from bench.stats import percentile
 
     cell.traffic = dict(cell.traffic, sessions=count, max_sessions=top)
@@ -42,7 +43,8 @@ def measure(cell, count: int, top: int, seed: int, seconds: float,
     q = max(1, lat.size // 4)
     w0, w1 = run.window
     return {"sessions": count,
-            "offered_per_s": loadgen.offered_rate(cell.traffic),
+            "offered_per_s": harness.generator(cell.traffic).offered_rate(
+                cell.traffic),
             "served_per_s": float(((run.done >= w0) & (run.done <= w1))
                                   .sum()) / seconds,
             "p50_ms": percentile(lat, 50) * 1e3,
@@ -72,8 +74,7 @@ def main(argv=None) -> int:
 
     cell = harness.load_cell(args.workload)
     harness.check_chips(int(cell.workload["chips"]))
-    lo, hi = cell.traffic["hr_bpm"]
-    period = 60.0 / ((lo + hi) / 2)
+    period = harness.generator(cell.traffic).period_s(cell.traffic)
     rows, warmed = [], set()
     counts = sorted(int(c) for c in args.counts.split(","))
     for count in counts:
